@@ -400,8 +400,11 @@ def _smoke_sharded() -> dict:
     partitioner.  Runs in a subprocess (the XLA device count is locked at
     first jax init — the benchmark process must keep its single device)
     and records per-partitioner edge-cut, p50/p95 update latency,
-    post-warmup retraces (must be 0) and oracle parity."""
+    post-warmup retraces (must be 0) and oracle parity.  The child is
+    pinned to the CPU: forced host devices exist only there, and on an
+    accelerator host the parent already holds the chip."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d" \
         % SHARDED_DEVICES
     src = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -416,7 +419,8 @@ def _smoke_sharded() -> dict:
                            + out.stderr[-3000:])
     payload = [ln for ln in out.stdout.splitlines()
                if ln.startswith("SHARDED-JSON:")]
-    return json.loads(payload[-1][len("SHARDED-JSON:"):])
+    return {"platform": "cpu",
+            **json.loads(payload[-1][len("SHARDED-JSON:"):])}
 
 
 _RECOVERY_CHILD = textwrap.dedent("""
@@ -454,7 +458,9 @@ def _smoke_recovery() -> dict:
     restored here — recovery wall time, replayed-batch count, post-restore
     retraces and parity against an uninterrupted session are recorded.
     Restore must be bit-for-bit (same r0, same batch seeds, same jitted
-    hot path) with zero post-restore retraces."""
+    hot path) with zero post-restore retraces.  The child is pinned to the
+    CPU (the parent holds the accelerator, if any); restore and the oracle
+    both run here, so parity compares like with like."""
     import select
     import shutil
     import signal
@@ -483,6 +489,7 @@ def _smoke_recovery() -> dict:
 
     store_dir = tempfile.mkdtemp(prefix="repro-recovery-")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     # child stderr goes to a FILE, not a pipe: a chatty XLA child filling
@@ -524,6 +531,7 @@ def _smoke_recovery() -> dict:
     shutil.rmtree(store_dir, ignore_errors=True)
     return {
         "n": sess.n,
+        "child_platform": "cpu",
         "killed_after_batches": RECOVERY_KILL_AFTER,
         "replayed_batches": rep.replayed_batches,
         "recovery_wall_s": round(recovery_wall_s, 4),
@@ -803,6 +811,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on section names")
     args = ap.parse_args()
+    from benchmarks.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         smoke()

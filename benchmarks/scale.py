@@ -299,5 +299,7 @@ if __name__ == "__main__":
                          "push datapoint)")
     ap.add_argument("--out", default=OUT)
     args = ap.parse_args()
+    from benchmarks.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(smoke=args.smoke, full=args.full, side=args.side,
          driver=args.driver, out=args.out)

@@ -48,6 +48,7 @@ import time
 from collections import Counter, deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.api.config import EngineConfig, ServingConfig
@@ -56,6 +57,18 @@ from repro.core import fault_domain as fd
 from repro.core import integrity as ig
 from repro.core.delta import coalesce_batches, validate_edge_batch
 from repro.core.graph import HostGraph
+
+
+def _is_compile_error(e: BaseException) -> bool:
+    """A failure to trace, lower or compile the update program.  It is a
+    property of the program, not of the moment, so a retry cannot help and
+    a dead slot would only hide it: the service raises it instead."""
+    if isinstance(e, (NotImplementedError, TypeError, RecursionError)):
+        return True
+    if "Lowering" in type(e).__name__:
+        return True
+    return (isinstance(e, jax.errors.JaxRuntimeError)
+            and "compile" in str(e).lower())
 
 
 class AdmissionRejected(RuntimeError):
@@ -224,6 +237,7 @@ class PageRankService:
             i: threading.Event() for i in range(len(self.sessions))}
         self._workers: Dict[int, threading.Thread] = {}
         self._watchdog_thread: Optional[threading.Thread] = None
+        self._worker_error: Optional[BaseException] = None
 
     @property
     def slots(self) -> int:
@@ -490,6 +504,8 @@ class PageRankService:
                             break
                         raise           # rejected batch: caller bug, no retry
                     except Exception as e:  # transient: backoff and retry
+                        if _is_compile_error(e):
+                            raise       # a broken program: no retry can help
                         last_err = e
                         result = None
                         if attempt < sv.max_retries:
@@ -733,6 +749,8 @@ class PageRankService:
         if self._running:
             deadline = time.time() + 600
             while time.time() < deadline:
+                if self._worker_error is not None:
+                    raise self._worker_error
                 with self._lock:
                     busy = (any(self._queues[i] for i in self._queues)
                             or bool(self._inflight)
@@ -754,7 +772,13 @@ class PageRankService:
             reqs = (self._take(stream)
                     if self.sessions[stream] is not None else [])
             if reqs:
-                if not self._dispatch(stream, reqs, gen):
+                try:
+                    ok = self._dispatch(stream, reqs, gen)
+                except BaseException as e:
+                    # surfaced to the caller by run_until_drained / stop
+                    self._worker_error = e
+                    raise
+                if not ok:
                     return          # slot died; the watchdog takes over
                 continue            # drain continuously while work exists
             ev.clear()
@@ -801,20 +825,22 @@ class PageRankService:
         empty first (shed/expired requests are not waited on)."""
         if not self._running:
             return
-        if drain:
-            self.run_until_drained()
-        self._running = False
-        for ev in self._wake.values():
-            ev.set()
-        for t in self._workers.values():
-            t.join(timeout=10)
-        if self._watchdog_thread is not None:
-            self._watchdog_thread.join(timeout=10)
-            self._watchdog_thread = None
-        if self._scrub_thread is not None:
-            self._scrub_thread.join(timeout=10)
-            self._scrub_thread = None
-        self._workers.clear()
+        try:
+            if drain:
+                self.run_until_drained()
+        finally:
+            self._running = False
+            for ev in self._wake.values():
+                ev.set()
+            for t in self._workers.values():
+                t.join(timeout=10)
+            if self._watchdog_thread is not None:
+                self._watchdog_thread.join(timeout=10)
+                self._watchdog_thread = None
+            if self._scrub_thread is not None:
+                self._scrub_thread.join(timeout=10)
+                self._scrub_thread = None
+            self._workers.clear()
 
     def __enter__(self) -> "PageRankService":
         return self.start()

@@ -137,7 +137,7 @@ def _seed_affected(mat_prev: ops.BlockSparse, mat_new: ops.BlockSparse,
     f = ind[:n_pad] & valid
     sb = fr.block_any(f, n_rb, block_size)
     cand = (bmat & sb[None, :]).any(axis=1)
-    n_cand = cand.sum()
+    n_cand = cand.sum(dtype=jnp.int32)
     cids = fr.compact_block_ids(cand, n_rb)
     fx = f.astype(mat_new.tiles.dtype)
     h_prev = ops.block_spmv_active_bucketed(
@@ -175,10 +175,7 @@ def _apply_operand_delta(out_deg, rb_in, rb_out, bmat,
 
 
 def _driver_cache_size() -> int:
-    try:
-        return int(pe._driver._cache_size())
-    except Exception:           # pragma: no cover - older jax fallback
-        return -1
+    return int(pe._driver._cache_size())
 
 
 # Cross-session retrace attribution.  The fused driver's jit cache is
@@ -303,10 +300,18 @@ class PageRankSession:
         self.engine_name = self.engine.name
         self.hg = hg
         self._dtype = config.resolved_dtype()
-        self.interpret = (pe.default_interpret() if interpret is None
+        self.interpret = (ops.default_interpret() if interpret is None
                           else interpret)
         self.backend = (config.resolved_backend
                         if self.engine_name == "pallas" else config.backend)
+        if (self.engine_name == "pallas" and self.backend == "pallas"
+                and not self.interpret and self._dtype == jnp.float64):
+            # Mosaic has no f64: fail here, not deep inside the first compile
+            raise ValueError(
+                "the compiled Pallas tile kernels run float32 ranks only "
+                "(the TPU has no float64 path); the resolved rank dtype is "
+                "float64 — pass EngineConfig(dtype=jnp.float32) or run "
+                "without jax_enable_x64")
         self._stream = (self.engine_name == "pallas" and hg is not None
                         and g is None)
         self._walk = "ppr" in registry.supports_of(self.engine)
@@ -619,7 +624,9 @@ class PageRankSession:
             r0h = np.asarray(r0)
             r_rel = np.zeros(self.n_pad, r0h.dtype)
             r_rel[:self.n] = r0h[order]
-            self.R = jnp.asarray(r_rel, self._dtype)
+            # born sharded over the mesh, like every drive's output
+            self.R = jax.device_put(r_rel.astype(self._dtype),
+                                    self.runtime._shardings()[0])
 
     def _init_walk(self, g: Optional[GraphSnapshot], r0) -> None:
         """Walk mode (``engine="walk"``): no sweeps, no pull operands — the

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.graph import HostGraph
 
@@ -309,11 +308,11 @@ def make_sweep(dg: DistGraph, mesh: Mesh, axis, *, alpha: float,
     specs_graph = (P(ax, None),) * 4 + (P(ax), P(ax))
     if exchange == "ring":
         specs_graph = specs_graph + (P(ax, None, None),) * 2
-    fn = shard_map(sweep, mesh=mesh,
-                   in_specs=specs_state + specs_graph,
-                   out_specs=(P(ax), P(ax), P(ax), P(ax, None), P(), P(),
-                              P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(sweep, mesh=mesh,
+                       in_specs=specs_state + specs_graph,
+                       out_specs=(P(ax), P(ax), P(ax), P(ax, None), P(),
+                                  P(), P(), P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -733,10 +732,12 @@ class DistRuntime:
         cache = self._cache
         stats = DistStats()
         for _ in range(max_sweeps):
-            (R, aff, rc, cache, outstanding, _max_dr, overflow,
+            (R, aff, rc, cache, outstanding, max_dr, overflow,
              edges) = sweep(R, aff, rc, cache, dg.src_in, dg.dst_in,
                             dg.src_out, dg.dst_out, dg.inv_deg,
                             dg.vertex_valid)
+            outstanding, max_dr, edges, overflow = jax.device_get(
+                (outstanding, max_dr, edges, overflow))
             stats.sweeps += 1
             stats.edges_processed += int(edges)
             if self.exchange == "delta":
@@ -746,7 +747,10 @@ class DistRuntime:
                     stats.delta_exchanges += 1
             else:
                 stats.full_exchanges += 1
-            if int(outstanding) == 0:
+            # RC-empty is the paper's criterion; max_dr <= tau is the fused
+            # pull driver's escape from the float limit cycle in which
+            # sub-ulp moves above tau_f re-mark the frontier forever
+            if int(outstanding) == 0 or float(max_dr) <= self._tau:
                 stats.converged = True
                 break
         self._cache = cache
@@ -811,17 +815,10 @@ class DistRuntime:
 
     def cache_size(self) -> int:
         """Total jit-cache entries of the sweep(s) + patch functions (the
-        sharded analogue of the fused driver's cache size; -1 when the
-        cache stats API is unavailable)."""
-        total = 0
+        sharded analogue of the fused driver's cache size)."""
         fns = list(self._sweeps.values()) + [_patch_slab, _patch_degrees,
                                              _scatter_mask]
-        for fn in fns:
-            try:
-                total += int(fn._cache_size())
-            except Exception:       # pragma: no cover - older jax fallback
-                return -1
-        return total
+        return sum(int(fn._cache_size()) for fn in fns)
 
     def fork(self) -> "DistRuntime":
         """Twin sharing every device array (immutable; patches are

@@ -120,9 +120,14 @@ def block_any(flags: jnp.ndarray, n_blocks: int, block_size: int
 
 def compact_block_ids(act: jnp.ndarray, n_blocks: int) -> jnp.ndarray:
     """Compacted active-block slot list: active ids first, then -1 padding.
-    jit-safe (static ``size=``); the Pallas kernels prefetch this list."""
-    return jnp.nonzero(act, size=n_blocks,
-                       fill_value=-1)[0].astype(jnp.int32)
+    jit-safe (static length); the Pallas kernels launch over this list.
+
+    Computed in int32 whatever the x64 setting: on a TPU a 64-bit prefix
+    sum over 16K blocks is emulated with pairs of u32 and overflows the
+    vector memory of its compile."""
+    pos = jnp.where(act, jnp.cumsum(act, dtype=jnp.int32) - 1, n_blocks)
+    ids = jnp.full((n_blocks + 1,), -1, jnp.int32)
+    return ids.at[pos].set(jnp.arange(n_blocks, dtype=jnp.int32))[:n_blocks]
 
 
 def expand_frontier(g: GraphSnapshot, changed: jnp.ndarray,

@@ -180,7 +180,7 @@ def compare_digests(device_arr, host_arr, *,
 def _tile_row_sums(tiles: jnp.ndarray, tile_cols: jnp.ndarray,
                    tile_idx: jnp.ndarray) -> jnp.ndarray:
     n_rb, mt = tile_cols.shape
-    T = tiles[tile_idx.reshape(n_rb, mt)]          # [n_rb, mt, B, B]
+    T = tiles[tile_idx.reshape(n_rb, mt)]          # [n_rb, mt, *tile]
     occ = (tile_cols >= 0)[:, :, None, None]
     return jnp.sum(jnp.where(occ, T, 0), axis=(1, 2, 3))
 

@@ -77,11 +77,6 @@ def build_pull_matrix(g: GraphSnapshot, dtype=np.float64,
                                   padded=padded)
 
 
-def default_interpret() -> bool:
-    """Pallas interpret mode on anything that is not a real TPU."""
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("n", "block_size", "mode", "expand",
                                    "active_policy", "max_iterations",
                                    "interpret", "backend", "tiered"))
@@ -146,7 +141,7 @@ def _driver(mat: ops.BlockSparse, R0, affected0, valid, out_deg,
         R, affected, RC, it, converged, dnf, deferred, ctr = state
         act_flags = affected if active_policy == "affected" else RC
         act_rb = fr.block_any(act_flags, n_rb, B)
-        n_act = act_rb.sum()
+        n_act = act_rb.sum(dtype=jnp.int32)
         no_work = n_act == 0
 
         if jacobi:
@@ -184,7 +179,7 @@ def _driver(mat: ops.BlockSparse, R0, affected0, valid, out_deg,
                 # next drive after admission) instead of syncing mid-sweep
                 deferred = deferred | (cand_rb & ~rb_res & do)
                 cand_rb = cand_rb & rb_res
-            n_cand = jnp.where(do, cand_rb.sum(), 0)
+            n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
             cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
             hitf = ops.block_spmv_active_bucketed(
                 mat, changed.astype(dtype), cids, n_cand, semiring="or",
@@ -207,9 +202,10 @@ def _driver(mat: ops.BlockSparse, R0, affected0, valid, out_deg,
             real_slot,
             rb_in[ids_c] + jnp.where(ch_cb[ids_c], rb_out[ids_c], 0),
             0).astype(jnp.float32)
-        pid = jnp.nonzero(participate, size=n_threads, fill_value=0)[0]
-        w = participate.sum()
-        tid = pid[jnp.arange(n_rb) % jnp.maximum(w, 1)]
+        pid = jnp.nonzero(participate, size=n_threads,
+                          fill_value=0)[0].astype(jnp.int32)
+        w = participate.sum(dtype=jnp.int32)
+        tid = pid[jnp.arange(n_rb, dtype=jnp.int32) % jnp.maximum(w, 1)]
         th_edges = jax.ops.segment_sum(slot_edges, tid,
                                        num_segments=n_threads)
         th_blocks = jax.ops.segment_sum(real_slot.astype(jnp.float32), tid,
@@ -305,7 +301,7 @@ def run_pallas(g: GraphSnapshot, R0: jnp.ndarray, affected0: jnp.ndarray,
     if not expand:
         tau_f = float("inf")
     if interpret is None:
-        interpret = default_interpret()
+        interpret = ops.default_interpret()
     backend = ops._resolve_backend(backend)
     plan = faults or flt.NO_FAULTS
     dtype = R0.dtype

@@ -132,7 +132,7 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
         # maxdr ≤ tau stop — no vertex's next move would exceed tau
         conv_now = (maxr <= tau_c) | at_floor
         pushable = rb_maxr > tau_c
-        n_push = pushable.sum()
+        n_push = pushable.sum(dtype=jnp.int32)
         do = ~conv_now & (n_push > 0)
         # defensive only: maxr > tau with every per-block max ≤ tau is
         # impossible (maxr IS the max over the per-block maxima)
@@ -177,7 +177,7 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
             cand_rb = cand & rb_res
         else:
             cand_rb = cand
-        n_cand = jnp.where(do, cand_rb.sum(), 0)
+        n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
         cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
         moved = jnp.where(sel_v, Rr, 0)
         pushed = ops.block_spmv_push_bucketed(
@@ -229,10 +229,7 @@ def push_stats_from_vec(sv: np.ndarray) -> Tuple[SweepStats, dict]:
 def push_cache_size() -> int:
     """Jit-cache entries of the push driver (the push session's retrace
     yardstick — separate from the pull driver's cache)."""
-    try:
-        return int(_push_driver._cache_size())
-    except Exception:           # pragma: no cover - older jax fallback
-        return -1
+    return int(_push_driver._cache_size())
 
 
 # ---------------------------------------------------------------------------

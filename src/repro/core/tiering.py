@@ -140,7 +140,8 @@ class HostTilePool:
         if plan.n_live > tiles.shape[0]:
             cap = ops.capacity_bucket(plan.n_live)
             tiles = np.concatenate(
-                [tiles, np.zeros((cap - tiles.shape[0], B, B), tiles.dtype)])
+                [tiles, np.zeros((cap - tiles.shape[0],) + tiles.shape[1:],
+                                 tiles.dtype)])
         # flat offsets stay int64: capacity * B^2 can exceed 2^31
         flat = (plan.tid.astype(np.int64) * (B * B)
                 + (rows % B) * B + (cols % B))
@@ -224,7 +225,7 @@ class HotSetManager:
         self._rb_slots: Dict[int, List[int]] = {}
         self._tables_dirty = True
         # device state
-        self._slab = jnp.zeros((cap, B, B), dtype)
+        self._slab = jnp.zeros((cap,) + pool.mat.tiles.shape[1:], dtype)
         self._dev_tile_cols = jnp.asarray(pool.tile_cols)
         self._dev_tile_idx = jnp.zeros((n_rb * pool.mat.max_tiles,),
                                        jnp.int32)
@@ -369,8 +370,7 @@ class HotSetManager:
             payload = self.pool.mat.tiles[tid_all]      # host gather
             k = len(slots)
             k_pad = ops.capacity_bucket(k, ADMIT_BUCKET)
-            B = self.pool.block
-            pay = np.zeros((k_pad, B, B), payload.dtype)
+            pay = np.zeros((k_pad,) + payload.shape[1:], payload.dtype)
             pay[:k] = payload
             # padded slots target the (dropped) out-of-bounds slot
             sl = np.full(k_pad, self.slab_cap, np.int32)

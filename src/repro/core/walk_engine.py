@@ -157,11 +157,7 @@ def cache_size() -> int:
     """Total jit-cache entries of the walk hot-path kernels (the walk
     engine's analog of the fused driver's cache; query kernels are
     excluded — they legitimately compile per (|S|, k) shape)."""
-    try:
-        return (int(_regen_step._cache_size())
-                + int(_patch_rows._cache_size()))
-    except Exception:           # pragma: no cover - older jax fallback
-        return -1
+    return int(_regen_step._cache_size()) + int(_patch_rows._cache_size())
 
 
 # ---------------------------------------------------------------------------

@@ -75,6 +75,17 @@ def capacity_bucket(n: int, base: int = TILE_CAP_BASE) -> int:
     return cap
 
 
+def tile_shape(block: int) -> Tuple[int, int]:
+    """Stored shape of one B×B tile: its B² entries, row-major, as rows of
+    a lane-dense last dimension — 128 lanes where B² allows (B=64 →
+    [32, 128]), else [B, B].  Entry (r, c) is at flat offset r·B + c either
+    way.  A TPU pads a 64-wide last dimension to 128 lanes and keeps such
+    an array transposed, so a [cap, 64, 64] pool would be copied into the
+    kernels' layout (at twice its size) on every launch."""
+    lanes = max(block, min(128, block * block))
+    return block * block // lanes, lanes
+
+
 def active_ladder(n_rb: int, base: int = ACTIVE_LADDER_BASE
                   ) -> Tuple[int, ...]:
     """Static ladder of active-block grid sizes for bucketed SpMV dispatch:
@@ -94,8 +105,10 @@ class BlockSparse:
     """Block-sparse matrix A [n_rows_pad, n_cols_pad] in B×B dense tiles.
 
     ``tiles[k]`` is the dense tile for the k-th stored (row-block, col-block)
-    pair; ``tile_cols[i, j]`` is the column-block of the j-th tile of
-    row-block i (or -1 padding); ``tile_idx`` flat-indexes into ``tiles``.
+    pair, stored row-major in :func:`tile_shape` (``tiles.reshape(-1, B, B)``
+    is the B×B view); ``tile_cols[i, j]`` is the column-block of the j-th
+    tile of row-block i (or -1 padding); ``tile_idx`` flat-indexes into
+    ``tiles``.
 
     ``tiles.shape[0]`` is a *capacity*, not a count: trailing tiles that no
     slot references are zero padding from the growth ladder.  The live tile
@@ -109,7 +122,7 @@ class BlockSparse:
     n_cols: int
     block: int
     max_tiles: int
-    tiles: jnp.ndarray       # [tile_capacity, B, B]
+    tiles: jnp.ndarray       # [tile_capacity, *tile_shape(B)]
     tile_cols: jnp.ndarray   # [n_rb, max_tiles] i32
     tile_idx: jnp.ndarray    # [n_rb * max_tiles] i32
 
@@ -213,6 +226,7 @@ def build_block_sparse(rows: np.ndarray, cols: np.ndarray, n_rows: int,
     tpos = np.searchsorted(uniq, key)
     flat = tpos * (block * block) + (rows % block) * block + (cols % block)
     np.add.at(tiles.reshape(-1), flat, vals)
+    tiles = tiles.reshape((cap,) + tile_shape(block))
 
     tiles_rb = (uniq // n_cb).astype(np.int64)
     tiles_cb = (uniq % n_cb).astype(np.int64)
@@ -371,7 +385,8 @@ def apply_delta(mat: BlockSparse, rows: np.ndarray, cols: np.ndarray,
         # tile-pool bucket overflow → grow to the next capacity bucket
         cap = capacity_bucket(plan.n_live)
         tiles = jnp.concatenate(
-            [tiles, jnp.zeros((cap - tiles.shape[0], B, B), tiles.dtype)])
+            [tiles, jnp.zeros((cap - tiles.shape[0],) + tiles.shape[1:],
+                              tiles.dtype)])
 
     # one bucketed device scatter applies every delta value
     b_pad = capacity_bucket(len(rows), DELTA_BATCH_BUCKET)
@@ -421,6 +436,17 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def default_interpret() -> bool:
+    """Pallas interpret mode on anything that is not a real TPU.  Every
+    kernel entry below resolves ``interpret=None`` through this, so a
+    caller that leaves the flag out compiles the kernels on the chip."""
+    return jax.default_backend() != "tpu"
+
+
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    return default_interpret() if interpret is None else bool(interpret)
+
+
 def _resolve_backend(backend: Optional[str]) -> str:
     backend = backend or default_backend()
     if backend not in BACKENDS:
@@ -439,7 +465,8 @@ def _block_spmv_xla(tile_idx: jnp.ndarray, tile_cols: jnp.ndarray,
     (batched B×B matvecs — dense MXU/AVX-friendly work, no interpreter)."""
     n_rb = tile_cols.shape[0]
     xb = x.reshape(-1, block)                              # [n_cb, B]
-    T = tiles[tile_idx.reshape(n_rb, max_tiles)]           # [n_rb, mt, B, B]
+    T = tiles.reshape(-1, block, block)[
+        tile_idx.reshape(n_rb, max_tiles)]                 # [n_rb, mt, B, B]
     X = xb[jnp.maximum(tile_cols, 0)]                      # [n_rb, mt, B]
     X = jnp.where((tile_cols >= 0)[:, :, None], X, 0)
     y = jnp.einsum("rmab,rmb->ra", T, X,
@@ -464,7 +491,8 @@ def _block_spmv_active_xla(active_ids: jnp.ndarray, tile_idx: jnp.ndarray,
     n_rb = tile_cols.shape[0]
     rb = jnp.maximum(active_ids, 0)
     cols = tile_cols[rb]                                   # [k, mt]
-    T = tiles[tile_idx.reshape(n_rb, max_tiles)[rb]]       # [k, mt, B, B]
+    T = tiles.reshape(-1, block, block)[
+        tile_idx.reshape(n_rb, max_tiles)[rb]]             # [k, mt, B, B]
     xb = x.reshape(-1, block)
     X = xb[jnp.maximum(cols, 0)]                           # [k, mt, B]
     live = (active_ids >= 0)[:, None] & (cols >= 0)
@@ -483,16 +511,18 @@ def _block_spmv_active_xla(active_ids: jnp.ndarray, tile_idx: jnp.ndarray,
 
 
 def block_spmv(mat: BlockSparse, x: jnp.ndarray, *, semiring: str = "sum",
-               interpret: bool = True,
+               interpret: Optional[bool] = None,
                backend: Optional[str] = None) -> jnp.ndarray:
     """y = A @ x over the requested semiring; x is zero-padded to block size.
 
     ``backend`` selects the Pallas kernels or the XLA tile path
     (:func:`default_backend` when None).  ``interpret`` applies to the
     Pallas backend only: True executes the kernel body under the
-    interpreter (CPU validation), False compiles for TPU.
+    interpreter (CPU validation), False compiles for TPU, None
+    (:func:`default_interpret`) follows the platform.
     """
     backend = _resolve_backend(backend)
+    interpret = _resolve_interpret(interpret)
     n_cb_pad = mat.n_cb * mat.block
     xp = jnp.zeros((n_cb_pad,), x.dtype).at[:x.shape[0]].set(x)
     if backend == "xla":
@@ -508,12 +538,13 @@ def block_spmv(mat: BlockSparse, x: jnp.ndarray, *, semiring: str = "sum",
 
 def block_spmv_active(mat: BlockSparse, x: jnp.ndarray,
                       active_ids: jnp.ndarray, *, semiring: str = "sum",
-                      interpret: bool = True,
+                      interpret: Optional[bool] = None,
                       backend: Optional[str] = None) -> jnp.ndarray:
     """Frontier-compacted y = A @ x restricted to the row-blocks in
     ``active_ids`` (compacted, -1-padded).  Rows of inactive blocks are
     UNDEFINED — mask with the active-block indicator before consuming."""
     backend = _resolve_backend(backend)
+    interpret = _resolve_interpret(interpret)
     n_cb_pad = mat.n_cb * mat.block
     xp = jnp.zeros((n_cb_pad,), x.dtype).at[:x.shape[0]].set(x)
     if backend == "xla":
@@ -533,7 +564,7 @@ def block_spmv_active(mat: BlockSparse, x: jnp.ndarray,
 def block_spmv_active_bucketed(mat: BlockSparse, x: jnp.ndarray,
                                active_ids: jnp.ndarray, n_active: jnp.ndarray,
                                *, semiring: str = "sum",
-                               interpret: bool = True,
+                               interpret: Optional[bool] = None,
                                backend: Optional[str] = None,
                                ladder: Optional[Sequence[int]] = None
                                ) -> jnp.ndarray:
@@ -546,8 +577,12 @@ def block_spmv_active_bucketed(mat: BlockSparse, x: jnp.ndarray,
     gather scales with the actual frontier, not ``n_rb``.  Trace-safe inside
     the fused driver's ``while_loop`` (the switch index is a traced scalar;
     every branch has static shapes).  O(log n_rb) branches are compiled once.
+    On the Pallas backend a bucket whose slot tables exceed the SMEM
+    prefetch budget runs as several launches
+    (:func:`repro.kernels.block_spmv.block_spmv.launch_rows`).
     """
     backend = _resolve_backend(backend)
+    interpret = _resolve_interpret(interpret)
     n_rb = mat.n_rb
     lad = tuple(ladder) if ladder is not None else active_ladder(n_rb)
     n_cb_pad = mat.n_cb * mat.block
@@ -577,7 +612,7 @@ def block_spmv_active_bucketed(mat: BlockSparse, x: jnp.ndarray,
 def block_spmv_push_bucketed(mat: BlockSparse, x: jnp.ndarray,
                              src_cb: jnp.ndarray,
                              active_ids: jnp.ndarray, n_active: jnp.ndarray,
-                             *, interpret: bool = True,
+                             *, interpret: Optional[bool] = None,
                              backend: Optional[str] = None,
                              ladder: Optional[Sequence[int]] = None
                              ) -> jnp.ndarray:
@@ -620,7 +655,7 @@ def block_adjacency(mat: BlockSparse) -> jnp.ndarray:
 
 def pagerank_pull_step(mat: BlockSparse, ranks: jnp.ndarray,
                        inv_out_deg: jnp.ndarray, n: int, *,
-                       alpha: float = 0.85, interpret: bool = True,
+                       alpha: float = 0.85, interpret: Optional[bool] = None,
                        backend: Optional[str] = None) -> jnp.ndarray:
     """One PageRank pull iteration with the tile SpMV:
     r' = (1-α)/n + α · A @ (r ⊙ 1/outdeg).  A[v,u] = 1 iff edge u→v."""
@@ -631,7 +666,7 @@ def pagerank_pull_step(mat: BlockSparse, ranks: jnp.ndarray,
 
 
 def frontier_expand_op(mat_t: BlockSparse, changed: jnp.ndarray, *,
-                       interpret: bool = True,
+                       interpret: Optional[bool] = None,
                        backend: Optional[str] = None) -> jnp.ndarray:
     """DF expansion: indicator of out-neighbors of ``changed`` vertices.
     ``mat_t`` must hold A[v,u]=1 iff edge u→v (same layout as the pull)."""

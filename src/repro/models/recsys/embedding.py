@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.dist.api import constrain
 
@@ -86,9 +85,9 @@ def sharded_lookup(table: jnp.ndarray, ids: jnp.ndarray, mesh: Mesh,
         return lax.psum(rows, axis)
 
     other = tuple(a for a in mesh.axis_names if a != axis)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis, None), P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis, None), P()),
+                       out_specs=P(), check_vma=False)
     return fn(table, ids)
 
 

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.transformer import layers as L
 from repro.models.transformer.config import TransformerConfig
@@ -275,11 +274,11 @@ def build_pipeline_loss(cfg: TransformerConfig, pcfg: PipelineConfig,
     in_param_specs = tuple(pspecs[n] for n in flat_names)
     batch_spec = P(dp_ax) if dp_ax else P()
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec, batch_spec) + in_param_specs,
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def loss_fn(params, batch):
         flat = [params[k] for k in flat_names]

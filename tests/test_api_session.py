@@ -221,6 +221,19 @@ class TestSessionLifecycle:
         ref = pr.numpy_reference(g0, iterations=300)
         assert pr.linf(sess.R[:g0.n], jnp.asarray(ref[:g0.n])) < 1e-8
 
+    def test_compiled_pallas_kernels_reject_f64_at_construction(self, dyn):
+        """The compiled Pallas kernels (a TPU's, interpret=False) have no
+        float64: the session refuses at construction, naming the fix,
+        instead of failing inside the first compile or casting down."""
+        hg0 = dyn[0]
+        cfg = EngineConfig(engine="pallas", backend="pallas", block_size=64)
+        assert cfg.resolved_dtype() == jnp.float64      # the suite's x64
+        with pytest.raises(ValueError, match="float64"):
+            PageRankSession.from_graph(hg0, config=cfg, interpret=False)
+        with pytest.raises(ValueError, match="float64"):
+            PageRankSession.from_snapshot(hg0.snapshot(block_size=64),
+                                          config=cfg, interpret=False)
+
     def test_bare_snapshot_session_cannot_update(self, dyn):
         _, g0, _, _, _, r_prev, dels, ins = dyn
         sess = PageRankSession.from_snapshot(

@@ -140,6 +140,47 @@ def test_pagerank_pull_step_op(backend):
                                atol=2e-6)
 
 
+@pytest.mark.parametrize("semiring", ["sum", "or"])
+def test_chunked_launches_match_xla_and_full_kernel(semiring):
+    """With the SMEM prefetch budget forced tiny, every ladder bucket above
+    four row-blocks splits into several launches; each bucket must still
+    match the XLA active path and the full kernel, padded slots included."""
+    from repro.kernels.block_spmv import block_spmv as bk
+    from repro.kernels.block_spmv.ops import (_block_spmv_active_xla,
+                                              active_ladder)
+    n = 300
+    rows, cols = _random_edges(n, n, 2500, seed=21)
+    mat = build_block_sparse(rows, cols, n, n, block=8, padded=True)
+    mt, B = mat.max_tiles, mat.block
+    tiny = 32 * mt                       # launch_rows == 4
+    assert bk.launch_rows(mt, tiny) == 4
+    rng = np.random.default_rng(22)
+    x = rng.random(mat.n_cb * B)
+    if semiring == "or":
+        x = x < 0.1
+    x = jnp.asarray(x, jnp.float32)
+    kw = dict(block=B, max_tiles=mt, semiring=semiring, interpret=True)
+    args = (mat.tile_idx, mat.tile_cols, mat.tiles, x)
+    full = np.asarray(bk.block_spmv_pallas(*args, **kw))
+    np.testing.assert_array_equal(
+        np.asarray(bk.block_spmv_pallas(*args, smem_budget=tiny, **kw)),
+        full)
+    act = np.sort(rng.permutation(mat.n_rb)[:mat.n_rb // 2 + 3])
+    for K in active_ladder(mat.n_rb, base=4):
+        take = act[:max(1, K - 3)]                    # leave padded slots
+        ids = np.full(K, -1, np.int32)
+        ids[:len(take)] = take
+        ids = jnp.asarray(ids)
+        y = np.asarray(bk.block_spmv_active_pallas(ids, *args,
+                                                   smem_budget=tiny, **kw))
+        ref = np.asarray(_block_spmv_active_xla(ids, *args, block=B,
+                                                max_tiles=mt,
+                                                semiring=semiring))
+        on = np.repeat(np.isin(np.arange(mat.n_rb), take), B)
+        np.testing.assert_allclose(y[on], ref[on], rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(y[on], full[on], rtol=1e-6, atol=1e-6)
+
+
 def test_frontier_expand_matches_engine_semantics():
     """OR kernel on the pull layout == out_neighbor_or on the snapshot."""
     from repro.core.graph import HostGraph, out_neighbor_or

@@ -147,6 +147,29 @@ class TestDeadlines:
                                  iterations=300)
         assert pr.linf(sess.R[:cur.n], jnp.asarray(ref[:cur.n])) < 1e-8
 
+    def test_compile_error_is_raised_not_retried(self, hg):
+        """A program that does not lower or compile is not a transient
+        fault: the dispatch raises it at once, with no retry and no dead
+        slot to hide it behind."""
+        svc = PageRankService(
+            [hg], config=_cfg(),
+            serving=ServingConfig(max_retries=2, retry_backoff_s=1e-3))
+        sess = svc.sessions[0]
+        calls = {"n": 0}
+
+        def broken_update(d, i, **kw):
+            calls["n"] += 1
+            raise NotImplementedError("float64")
+
+        sess.update = broken_update
+        bs, _ = _batches(hg, 1)
+        svc.submit(0, *bs[0])
+        with pytest.raises(NotImplementedError, match="float64"):
+            svc.run_until_drained()
+        assert calls["n"] == 1
+        rep = svc.report()
+        assert rep["retries"] == 0
+
 
 # ---------------------------------------------------------------------------
 # degraded-mode reads
