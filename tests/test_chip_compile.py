@@ -7,7 +7,8 @@ prefetched slot tables, index maps that trace to int64 under x64, block
 shapes the chip refuses — at the n = 1,048,576 layout of
 ``grid_road(1024)``: 16,384 row-blocks of B = 64, 16 tile slots per row,
 float32 ranks.  The suite enables x64, so these compiles also guard the
-kernels against it.
+kernels against it.  One more case lowers a kernel from two checkouts and
+checks that the scripts' cache settings give both the same cache key.
 
 The topology is described inside a module-scoped fixture (never at
 import): only one process may load the TPU library at a time, so only the
@@ -15,13 +16,16 @@ worker that runs this file loads it.  The persistent compilation cache is
 off around these compiles; an entry written for a described chip cannot be
 read back without one.
 """
+import importlib.util
 import os
+import shutil
 
 import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks import compile_cache
 from repro.core import pallas_engine as pe
 from repro.core import push_engine as pshe
 from repro.kernels.block_spmv import block_spmv as bk
@@ -107,3 +111,58 @@ def test_push_driver_compiles_at_1m(one_chip):
         n=N_PAD, block_size=B, max_iterations=MAX_ITERATIONS,
         interpret=False, backend="pallas").compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+KERNEL_SRC = os.path.join("src", "repro", "kernels", "block_spmv",
+                          "block_spmv.py")
+
+
+def _lowered_from(root, one_chip):
+    """Text of the active kernel lowered from the copy of its source file
+    under the checkout ``root`` (debug info off, as in the cache key): the
+    Mosaic payload inside it keeps the kernel's source locations."""
+    spec = importlib.util.spec_from_file_location("block_spmv_copy",
+                                                  root / KERNEL_SRC)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    S = _spec(one_chip)
+    n_rb, mt = 64, 4
+    return mod.block_spmv_active_pallas.lower(
+        S((n_rb,), jnp.int32), S((n_rb * mt,), jnp.int32),
+        S((n_rb, mt), jnp.int32), S((256,) + tile_shape(B), jnp.float32),
+        S((n_rb * B,), jnp.float32), block=B, max_tiles=mt).as_text()
+
+
+def _lowered_via_caller(root, one_chip):
+    return _lowered_from(root, one_chip)
+
+
+@pytest.mark.parametrize("scripts_settings", [True, False])
+def test_kernel_cache_key_follows_checkout(one_chip, tmp_path, monkeypatch,
+                                           scripts_settings):
+    """With ``enable_compile_cache``'s settings, two checkouts of the same
+    kernel, reached through different callers, lower to the same program
+    text, so their cache entries are shared; without them the absolute
+    source path and the call stack tell them apart."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = ("jax_include_full_tracebacks_in_locations",
+             "jax_hlo_source_file_canonicalization_regex",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in names}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    texts = []
+    try:
+        for co in ("a", "b"):
+            root = tmp_path / co / "checkout"
+            os.makedirs(os.path.dirname(root / KERNEL_SRC))
+            shutil.copy(os.path.join(repo, KERNEL_SRC), root / KERNEL_SRC)
+            if scripts_settings:
+                monkeypatch.setattr(compile_cache, "CHECKOUT", str(root))
+                compile_cache.enable_compile_cache()
+            lower = _lowered_from if co == "a" else _lowered_via_caller
+            texts.append(lower(root, one_chip))
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+    assert "tpu_custom_call" in texts[0]
+    assert (texts[0] == texts[1]) is scripts_settings
