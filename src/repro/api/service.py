@@ -50,6 +50,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.config import EngineConfig, ServingConfig
 from repro.api.session import PageRankSession, StreamBatchResult
@@ -448,104 +449,109 @@ class PageRankService:
         """Run one dispatch for ``stream``: coalesce the claimed requests
         into one batch, update with retry/backoff, retire.  Returns False
         when the slot died (requests re-queued for the failover drain)."""
-        sv = self.serving
-        self._heartbeat.busy(stream)
-        try:
-            fault = self._consume_fault(stream)
-            if fault is not None and fault.kind == "stuck":
-                # the stall sits BEFORE the update: the slot holds work,
-                # the heartbeat goes stale, and nothing has touched session
-                # or WAL state — so the watchdog may safely re-drain
-                time.sleep(fault.stall_s)
-            if fault is not None and fault.kind == "dead":
-                sess = self.sessions[stream]
-                if sess is not None:
-                    # crash-stop, not a clean close(): drop the service
-                    # backref first so _detach doesn't run — the slot stays
-                    # registered (dead) and its queue survives for the drain
-                    sess._service = None
-                    sess.close()
-            if gen != self._slot_gen[stream]:
-                # the watchdog failed this slot over while we stalled: the
-                # respawned slot owns these requests now — abandon them
-                # without touching the zombie session
-                with self._lock:
-                    self._inflight.pop(stream, None)
-                return True
-            if len(reqs) == 1:
-                dels, ins = reqs[0].deletions, reqs[0].insertions
-            else:
-                sess = self.sessions[stream]
-                n = sess.n if sess is not None else 0
-                dels, ins = coalesce_batches(
-                    [(r.deletions, r.insertions) for r in reqs], n)
-            start = time.perf_counter()
-            for req in reqs:
-                req.started_s = start
-            last_err: Optional[BaseException] = None
-            result = None
-            # the slot lock serializes the session-mutating portion of a
-            # dispatch against the integrity scrubber (which only ever
-            # try-acquires, so dispatch never waits on a scrub in progress
-            # for more than one verify pass)
-            with self._slot_locks[stream]:
-                for attempt in range(sv.max_retries + 1):
+        with TraceAnnotation("service.dispatch"):
+            sv = self.serving
+            self._heartbeat.busy(stream)
+            try:
+                fault = self._consume_fault(stream)
+                if fault is not None and fault.kind == "stuck":
+                    # the stall sits BEFORE the update: the slot holds work,
+                    # the heartbeat goes stale, and nothing has touched session
+                    # or WAL state — so the watchdog may safely re-drain
+                    time.sleep(fault.stall_s)
+                if fault is not None and fault.kind == "dead":
                     sess = self.sessions[stream]
-                    if sess is None or sess.closed:
-                        last_err = ValueError(
-                            f"stream {stream} session is closed")
-                        break           # permanent: no retry can help
-                    try:
-                        result = sess.update(dels, ins)
-                        break
-                    except ValueError as e:
-                        if sess.closed:  # slot died mid-dispatch
-                            last_err = e
-                            break
-                        raise           # rejected batch: caller bug, no retry
-                    except Exception as e:  # transient: backoff and retry
-                        if _is_compile_error(e):
-                            raise       # a broken program: no retry can help
-                        last_err = e
-                        result = None
-                        if attempt < sv.max_retries:
-                            with self._lock:
-                                self._retries += 1
-                            time.sleep(sv.retry_backoff_s * (2 ** attempt))
-            for req in reqs:
-                req.attempts = attempt + 1
-            if result is None:
-                for req in reqs:
-                    req.error = repr(last_err)
-                self._requeue(stream, reqs, gen)
-                with self._lock:
-                    if gen == self._slot_gen[stream]:
-                        self._dead.setdefault(stream, repr(last_err))
-                return False
-            done = time.perf_counter()
-            with self._lock:
+                    if sess is not None:
+                        # crash-stop, not a clean close(): drop the service
+                        # backref first so _detach doesn't run — the slot
+                        # stays registered (dead) and its queue survives for
+                        # the drain
+                        sess._service = None
+                        sess.close()
                 if gen != self._slot_gen[stream]:
-                    # the watchdog declared us stuck mid-update and drained
-                    # these requests to a respawned slot — our result went
-                    # to the orphaned pre-failover session; retiring it too
-                    # would double-apply, so abandon it
+                    # the watchdog failed this slot over while we stalled: the
+                    # respawned slot owns these requests now — abandon them
+                    # without touching the zombie session
+                    with self._lock:
+                        self._inflight.pop(stream, None)
                     return True
+                if len(reqs) == 1:
+                    dels, ins = reqs[0].deletions, reqs[0].insertions
+                else:
+                    sess = self.sessions[stream]
+                    n = sess.n if sess is not None else 0
+                    with TraceAnnotation("service.coalesce"):
+                        dels, ins = coalesce_batches(
+                            [(r.deletions, r.insertions) for r in reqs], n)
+                start = time.perf_counter()
                 for req in reqs:
-                    req.result = result
-                    req.done_s = done
-                    req.done = True
-                    if (req.deadline_at_s is not None
-                            and done > req.deadline_at_s):
-                        req.deadline_missed = True
-                        self._deadline_misses += 1
-                self.finished.extend(reqs)
-                self._inflight.pop(stream, None)
-                self._dispatches[stream] += 1
-            if sv.degraded_reads:
-                self._refresh_snapshot(stream)
-            return True
-        finally:
-            self._heartbeat.idle(stream)
+                    req.started_s = start
+                last_err: Optional[BaseException] = None
+                result = None
+                # the slot lock serializes the session-mutating portion of a
+                # dispatch against the integrity scrubber (which only ever
+                # try-acquires, so dispatch never waits on a scrub in progress
+                # for more than one verify pass)
+                with self._slot_locks[stream]:
+                    for attempt in range(sv.max_retries + 1):
+                        sess = self.sessions[stream]
+                        if sess is None or sess.closed:
+                            last_err = ValueError(
+                                f"stream {stream} session is closed")
+                            break           # permanent: no retry can help
+                        try:
+                            result = sess.update(dels, ins)
+                            break
+                        except ValueError as e:
+                            if sess.closed:  # slot died mid-dispatch
+                                last_err = e
+                                break
+                            raise   # rejected batch: caller bug, no retry
+                        except Exception as e:  # transient: backoff and retry
+                            if _is_compile_error(e):
+                                raise   # a broken program: no retry helps
+                            last_err = e
+                            result = None
+                            if attempt < sv.max_retries:
+                                with self._lock:
+                                    self._retries += 1
+                                with TraceAnnotation("service.retry_backoff"):
+                                    time.sleep(sv.retry_backoff_s
+                                               * (2 ** attempt))
+                for req in reqs:
+                    req.attempts = attempt + 1
+                if result is None:
+                    for req in reqs:
+                        req.error = repr(last_err)
+                    self._requeue(stream, reqs, gen)
+                    with self._lock:
+                        if gen == self._slot_gen[stream]:
+                            self._dead.setdefault(stream, repr(last_err))
+                    return False
+                done = time.perf_counter()
+                with self._lock:
+                    if gen != self._slot_gen[stream]:
+                        # the watchdog declared us stuck mid-update and drained
+                        # these requests to a respawned slot — our result went
+                        # to the orphaned pre-failover session; retiring it too
+                        # would double-apply, so abandon it
+                        return True
+                    for req in reqs:
+                        req.result = result
+                        req.done_s = done
+                        req.done = True
+                        if (req.deadline_at_s is not None
+                                and done > req.deadline_at_s):
+                            req.deadline_missed = True
+                            self._deadline_misses += 1
+                    self.finished.extend(reqs)
+                    self._inflight.pop(stream, None)
+                    self._dispatches[stream] += 1
+                if sv.degraded_reads:
+                    self._refresh_snapshot(stream)
+                return True
+            finally:
+                self._heartbeat.idle(stream)
 
     # -- watchdog (session fault domain) -------------------------------------
     def _slot_has_work(self, stream: int) -> bool:
@@ -579,72 +585,74 @@ class PageRankService:
         queue.  The event lands as a session-domain ``RecoveryRecord`` in
         the respawned session's ``report()`` and under
         ``report()["watchdog"]``."""
-        t0 = time.perf_counter()
-        with self._lock:
-            # mark the slot mid-recovery so run_until_drained() doesn't
-            # mistake the held-for-drain window for an idle service
-            self._recovering.add(stream)
-            stranded = (self._inflight.pop(stream, [])
-                        + list(self._queues[stream]))
-            self._queues[stream].clear()
-            self._slot_gen[stream] += 1     # zombie workers see a stale gen
-            gen = self._slot_gen[stream]
-        try:
-            sess = self.sessions[stream]
-            if kind == "stuck" and sess is not None and not sess.closed:
-                # close the stuck session: a zombie worker waking later hits
-                # "session is closed" before any WAL append — the respawn
-                # owns the store exclusively from here (backref dropped
-                # first so _detach doesn't unregister the slot)
-                sess._service = None
-                sess.close()
-            if self._store_dirs.get(stream) is None:
+        with TraceAnnotation("service.failover"):
+            t0 = time.perf_counter()
+            with self._lock:
+                # mark the slot mid-recovery so run_until_drained() doesn't
+                # mistake the held-for-drain window for an idle service
+                self._recovering.add(stream)
+                stranded = (self._inflight.pop(stream, [])
+                            + list(self._queues[stream]))
+                self._queues[stream].clear()
+                self._slot_gen[stream] += 1  # zombie workers: stale gen
+                gen = self._slot_gen[stream]
+            try:
+                sess = self.sessions[stream]
+                if kind == "stuck" and sess is not None and not sess.closed:
+                    # close the stuck session: a zombie worker waking later
+                    # hits "session is closed" before any WAL append — the
+                    # respawn owns the store exclusively from here (backref
+                    # dropped first so _detach doesn't unregister the slot)
+                    sess._service = None
+                    sess.close()
+                if self._store_dirs.get(stream) is None:
+                    with self._lock:
+                        for req in stranded:
+                            self._shed(req, "slot_dead",
+                                       f"stream {stream} {kind} with no "
+                                       "durable store to respawn from — "
+                                       "request shed")
+                        self._dead[stream] = f"{kind}; no durable store"
+                        self._watchdog_events.append(fd.RecoveryRecord(
+                            domain="session", batch_index=-1,
+                            wall_time_s=time.perf_counter() - t0,
+                            stream=stream, kind=kind,
+                            drained_requests=0,
+                            description=(f"slot {stream} {kind}; no store — "
+                                         f"{len(stranded)} request(s) shed")
+                        ).to_dict())
+                    return False
+                self.failover(stream)
                 with self._lock:
-                    for req in stranded:
-                        self._shed(req, "slot_dead",
-                                   f"stream {stream} {kind} with no durable "
-                                   "store to respawn from — request shed")
-                    self._dead[stream] = f"{kind}; no durable store"
-                    self._watchdog_events.append(fd.RecoveryRecord(
-                        domain="session", batch_index=-1,
-                        wall_time_s=time.perf_counter() - t0,
-                        stream=stream, kind=kind,
-                        drained_requests=0,
-                        description=(f"slot {stream} {kind}; no store — "
-                                     f"{len(stranded)} request(s) shed")
-                    ).to_dict())
-                return False
-            self.failover(stream)
-            with self._lock:
-                # prepend (like _requeue): a durable dead slot keeps
-                # accepting submits while the respawn restores, and those
-                # were admitted AFTER the stranded batches — appending the
-                # stranded run behind them would invert the apply order
-                # vs the accepted-batch lineage (delta batches are
-                # order-sensitive: a later delete can cancel an earlier
-                # insert of the same edge, so inversion silently diverges
-                # the served ranks from the oracle)
-                self._queues[stream].extendleft(reversed(stranded))
-            rec = fd.RecoveryRecord(
-                domain="session",
-                batch_index=self.sessions[stream]._batch_index,
-                wall_time_s=time.perf_counter() - t0,
-                stream=stream, kind=kind, drained_requests=len(stranded),
-                replayed_batches=(self.sessions[stream]
-                                  .report().replayed_batches),
-                description=(f"slot {stream} {kind} — respawned from "
-                             f"store, {len(stranded)} queued batch(es) "
-                             "drained to the new session"))
-            self.sessions[stream]._recoveries.append(rec)
-            with self._lock:
-                self._watchdog_events.append(rec.to_dict())
-            if self._running:
-                self._spawn_worker(stream, gen)
-                self._wake[stream].set()
-            return True
-        finally:
-            with self._lock:
-                self._recovering.discard(stream)
+                    # prepend (like _requeue): a durable dead slot keeps
+                    # accepting submits while the respawn restores, and those
+                    # were admitted AFTER the stranded batches — appending the
+                    # stranded run behind them would invert the apply order
+                    # vs the accepted-batch lineage (delta batches are
+                    # order-sensitive: a later delete can cancel an earlier
+                    # insert of the same edge, so inversion silently diverges
+                    # the served ranks from the oracle)
+                    self._queues[stream].extendleft(reversed(stranded))
+                rec = fd.RecoveryRecord(
+                    domain="session",
+                    batch_index=self.sessions[stream]._batch_index,
+                    wall_time_s=time.perf_counter() - t0,
+                    stream=stream, kind=kind, drained_requests=len(stranded),
+                    replayed_batches=(self.sessions[stream]
+                                      .report().replayed_batches),
+                    description=(f"slot {stream} {kind} — respawned from "
+                                 f"store, {len(stranded)} queued batch(es) "
+                                 "drained to the new session"))
+                self.sessions[stream]._recoveries.append(rec)
+                with self._lock:
+                    self._watchdog_events.append(rec.to_dict())
+                if self._running:
+                    self._spawn_worker(stream, gen)
+                    self._wake[stream].set()
+                return True
+            finally:
+                with self._lock:
+                    self._recovering.discard(stream)
 
     # -- integrity scrubber (corruption fault domain, docs/FAULTS.md) --------
     def _scrub_eligible(self, stream: int) -> Optional[PageRankSession]:
@@ -671,7 +679,7 @@ class PageRankService:
             sess = self._scrub_eligible(i)
             if sess is None:
                 continue
-            with self._slot_locks[i]:
+            with self._slot_locks[i], TraceAnnotation("service.scrub"):
                 try:
                     rep = sess.verify(deep=deep, repair=repair)
                 except ValueError:      # closed between check and acquire
@@ -702,7 +710,8 @@ class PageRankService:
                 continue                # busy slot: next pass gets it
             rep = None
             try:
-                rep = sess.verify(deep=True)
+                with TraceAnnotation("service.scrub"):
+                    rep = sess.verify(deep=True)
             except ValueError:          # closed mid-scrub
                 pass
             finally:
@@ -851,66 +860,68 @@ class PageRankService:
 
     # -- degraded-mode reads --------------------------------------------------
     def _refresh_snapshot(self, stream: int) -> None:
-        sess = self.sessions[stream]
-        if sess is None or sess.closed:
-            return
-        snap = _ReadSnapshot(sess.fork(), time.perf_counter(),
-                             sess._batch_index)
-        with self._lock:
-            self._snapshots[stream] = snap
+        with TraceAnnotation("service.snapshot"):
+            sess = self.sessions[stream]
+            if sess is None or sess.closed:
+                return
+            snap = _ReadSnapshot(sess.fork(), time.perf_counter(),
+                                 sess._batch_index)
+            with self._lock:
+                self._snapshots[stream] = snap
 
     def _read(self, stream: int, op) -> ReadResult:
-        self._check_stream(stream)
-        t0 = time.perf_counter()
-        snap = self._snapshots.get(stream) if self.serving.degraded_reads \
-            else None
-        live = self.sessions[stream]
-        if snap is not None:
-            # refresh proactively at a fraction of the budget so served
-            # staleness stays under budget even under sustained update
-            # load — fork() only rebinds immutable device arrays, so
-            # refreshing while the dispatcher drives is safe and cheap
-            refresh_at = (self.serving.staleness_budget_s
-                          * self.serving.snapshot_refresh_frac)
-            if (t0 - snap.taken_s > refresh_at
-                    and live is not None and not live.closed):
-                self._refresh_snapshot(stream)
-                with self._lock:
-                    self._snapshot_refreshes += 1
-                snap = self._snapshots[stream]
-            op_start = time.perf_counter()
-            values, vertices = op(snap.sess)
-            lag = 0
-            if live is not None:
-                # a closed (mid-failover) session's batch index is still
-                # the committed high-water mark for the stream
-                lag = max(0, live._batch_index - snap.batch_index)
-                if not live.closed:
-                    live._queries += 1  # degraded reads count for the slot
-            # staleness = the age of the served data when the read began
-            # (the read's own wall time is latency, not staleness) — and
-            # only while the snapshot actually DIVERGES from committed
-            # state (lag > 0).  A snapshot at the live batch index IS the
-            # newest committed state no matter how long ago it was taken:
-            # an idle slot, or one mid-failover (nothing commits anywhere
-            # until the respawn replays), serves current data
-            stale = (max(0.0, op_start - snap.taken_s) if lag > 0 else 0.0)
-            res = ReadResult(values=values, vertices=vertices,
-                             stream=stream, staleness_s=stale,
-                             lag_updates=lag, degraded=True)
-        else:
-            if live is None or live.closed:
-                raise ValueError(f"stream {stream} is closed and "
-                                 "degraded reads are disabled")
-            values, vertices = op(live)
-            res = ReadResult(values=values, vertices=vertices,
-                             stream=stream, staleness_s=0.0,
-                             lag_updates=0, degraded=False)
-        with self._lock:
-            self._query_walls.append(time.perf_counter() - t0)
-            self._query_staleness.append(res.staleness_s)
-            self._query_lags.append(res.lag_updates)
-        return res
+        with TraceAnnotation("service.read"):
+            self._check_stream(stream)
+            t0 = time.perf_counter()
+            snap = self._snapshots.get(stream) if self.serving.degraded_reads \
+                else None
+            live = self.sessions[stream]
+            if snap is not None:
+                # refresh proactively at a fraction of the budget so served
+                # staleness stays under budget even under sustained update
+                # load — fork() only rebinds immutable device arrays, so
+                # refreshing while the dispatcher drives is safe and cheap
+                refresh_at = (self.serving.staleness_budget_s
+                              * self.serving.snapshot_refresh_frac)
+                if (t0 - snap.taken_s > refresh_at
+                        and live is not None and not live.closed):
+                    self._refresh_snapshot(stream)
+                    with self._lock:
+                        self._snapshot_refreshes += 1
+                    snap = self._snapshots[stream]
+                op_start = time.perf_counter()
+                values, vertices = op(snap.sess)
+                lag = 0
+                if live is not None:
+                    # a closed (mid-failover) session's batch index is still
+                    # the committed high-water mark for the stream
+                    lag = max(0, live._batch_index - snap.batch_index)
+                    if not live.closed:
+                        live._queries += 1  # degraded reads count for the slot
+                # staleness = the age of the served data when the read began
+                # (the read's own wall time is latency, not staleness) — and
+                # only while the snapshot actually DIVERGES from committed
+                # state (lag > 0).  A snapshot at the live batch index IS the
+                # newest committed state no matter how long ago it was taken:
+                # an idle slot, or one mid-failover (nothing commits anywhere
+                # until the respawn replays), serves current data
+                stale = (max(0.0, op_start - snap.taken_s) if lag > 0 else 0.0)
+                res = ReadResult(values=values, vertices=vertices,
+                                 stream=stream, staleness_s=stale,
+                                 lag_updates=lag, degraded=True)
+            else:
+                if live is None or live.closed:
+                    raise ValueError(f"stream {stream} is closed and "
+                                     "degraded reads are disabled")
+                values, vertices = op(live)
+                res = ReadResult(values=values, vertices=vertices,
+                                 stream=stream, staleness_s=0.0,
+                                 lag_updates=0, degraded=False)
+            with self._lock:
+                self._query_walls.append(time.perf_counter() - t0)
+                self._query_staleness.append(res.staleness_s)
+                self._query_lags.append(res.lag_updates)
+            return res
 
     def query(self, stream: int, vertices) -> ReadResult:
         """Ranks of the given vertices, served degraded-mode (from the

@@ -76,6 +76,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.api import registry
 from repro.api.config import EngineConfig
@@ -117,6 +118,7 @@ class SweepCapWarning(RuntimeWarning):
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("block_size", "interpret", "backend"))
+@jax.named_scope("df.seed")
 def _seed_affected(mat_prev: ops.BlockSparse, mat_new: ops.BlockSparse,
                    bmat, batch, valid, *, block_size: int, interpret: bool,
                    backend: str) -> jnp.ndarray:
@@ -151,6 +153,7 @@ def _seed_affected(mat_prev: ops.BlockSparse, mat_new: ops.BlockSparse,
 
 
 @partial(jax.jit, static_argnames=("block",))
+@jax.named_scope("delta.scatter")
 def _apply_operand_delta(out_deg, rb_in, rb_out, bmat,
                          rows, cols, vals, *, block: int):
     """O(batch) device-side update of the engine-operand mirrors from the
@@ -197,7 +200,8 @@ _NEW_BUCKET_ACTIVE = 0          # of those, currently mid-drive
 @dataclasses.dataclass
 class StreamBatchResult:
     """Outcome of one update step."""
-    ranks: jnp.ndarray            # [n_pad] post-batch converged ranks
+    ranks: Optional[jnp.ndarray]  # [n_pad] post-batch converged ranks
+    #                               (None in the session's kept history)
     stats: SweepStats
     wall_time_s: float            # full step: delta + seed + converge
     batch_edges: int              # raw batch size (before no-op filtering)
@@ -699,28 +703,30 @@ class PageRankSession:
         part, alive, delay, crashed = self._fault_tables
         tiered = self._tiered
         rb_res = self.hot.rb_res if tiered else self._rb_res_full
-        R, stats_vec, deferred = pe._driver(
-            self.inc.mat, R0, affected, self.valid, self._out_deg,
-            self._rb_in, self._rb_out, self._bmat, rb_res,
-            self._alpha, self._tau, self._tau_f,
-            part, alive, delay, crashed,
-            n=self.n, block_size=self.block_size, mode=cfg.mode,
-            expand=expand, active_policy=cfg.active_policy,
-            max_iterations=cfg.max_iterations, interpret=self.interpret,
-            backend=self.backend, tiered=tiered)
         icfg = cfg.integrity
         fused = (icfg is not None and icfg.fused
                  and self._r_verified is not None)
-        # everything riding the drive — invariants AND the tiered deferral
-        # indicator — is fetched in the SAME block_until_ready: one sync
-        tail = []
-        if fused:
-            inv = ig.invariant_vec(R, self._r_verified, self.valid)
-            tail.append(inv.astype(stats_vec.dtype))
-        if tiered:
-            tail.append(deferred.astype(stats_vec.dtype))
-        sv = np.asarray(jax.block_until_ready(       # the single sync
-            jnp.concatenate([stats_vec] + tail) if tail else stats_vec))
+        with TraceAnnotation("session.drive"):
+            R, stats_vec, deferred = pe._driver(
+                self.inc.mat, R0, affected, self.valid, self._out_deg,
+                self._rb_in, self._rb_out, self._bmat, rb_res,
+                self._alpha, self._tau, self._tau_f,
+                part, alive, delay, crashed,
+                n=self.n, block_size=self.block_size, mode=cfg.mode,
+                expand=expand, active_policy=cfg.active_policy,
+                max_iterations=cfg.max_iterations, interpret=self.interpret,
+                backend=self.backend, tiered=tiered)
+            # everything riding the drive — invariants AND the tiered
+            # deferral indicator — is fetched in the SAME
+            # block_until_ready: one sync
+            tail = []
+            if fused:
+                inv = ig.invariant_vec(R, self._r_verified, self.valid)
+                tail.append(inv.astype(stats_vec.dtype))
+            if tiered:
+                tail.append(deferred.astype(stats_vec.dtype))
+            sv = np.asarray(jax.block_until_ready(       # the single sync
+                jnp.concatenate([stats_vec] + tail) if tail else stats_vec))
         def_pending = False
         if tiered:
             self._deferred_rb = sv[-self.n_rb:] != 0
@@ -759,8 +765,9 @@ class PageRankSession:
     def _admit(self, want_rb) -> None:
         """Admit row-blocks into the hot slab and rebind the device view
         (tiered streams only)."""
-        self.hot.admit(want_rb)
-        self.inc.mat = self.hot.view()
+        with TraceAnnotation("session.admit"):
+            self.hot.admit(want_rb)
+            self.inc.mat = self.hot.view()
 
     def _mask_from_indices(self, idx: np.ndarray) -> jnp.ndarray:
         """Device indicator from a host index list: only the bucket-padded
@@ -878,15 +885,16 @@ class PageRankSession:
         cfg = self.config
         tiered = self._tiered
         rb_res = self.hot.rb_res if tiered else self._rb_res_full
-        P, Rr, stats_vec, deferred = pshe._push_driver(
-            self.inc.mat, P0, self._residual, self.valid, self._out_deg,
-            self._rb_out, self._bmat, rb_res, self._alpha, self._tau,
-            n=self.n, block_size=self.block_size,
-            max_iterations=cfg.max_iterations, interpret=self.interpret,
-            backend=self.backend, tiered=tiered)
-        tail = [deferred.astype(stats_vec.dtype)] if tiered else []
-        sv = np.asarray(jax.block_until_ready(       # the single sync
-            jnp.concatenate([stats_vec] + tail) if tail else stats_vec))
+        with TraceAnnotation("session.drive"):
+            P, Rr, stats_vec, deferred = pshe._push_driver(
+                self.inc.mat, P0, self._residual, self.valid, self._out_deg,
+                self._rb_out, self._bmat, rb_res, self._alpha, self._tau,
+                n=self.n, block_size=self.block_size,
+                max_iterations=cfg.max_iterations, interpret=self.interpret,
+                backend=self.backend, tiered=tiered)
+            tail = [deferred.astype(stats_vec.dtype)] if tiered else []
+            sv = np.asarray(jax.block_until_ready(       # the single sync
+                jnp.concatenate([stats_vec] + tail) if tail else stats_vec))
         if tiered:
             self._deferred_rb = sv[-self.n_rb:] != 0
             sv = sv[:-self.n_rb]
@@ -1021,81 +1029,86 @@ class PageRankSession:
         path), ``"dt"`` (reachability marking), ``"nd"`` (warm start, all
         affected) or ``"static"`` (cold start, all affected).  In stream
         mode everything except the ``dt`` marking stays snapshot-free."""
-        self._ensure_open()
-        if variant not in VARIANTS:
-            raise ValueError(f"variant={variant!r} invalid; "
-                             f"expected one of {VARIANTS}")
-        if self.hg is None:
-            raise ValueError(
-                "this session wraps a bare snapshot (from_snapshot without "
-                "hg=); build it with PageRankSession.from_graph to stream "
-                "updates")
-        # validate BEFORE the WAL append and before any device scatter: a
-        # NaN-weighted, duplicate, out-of-range or ambiguous batch raises
-        # here, is never durably logged, and never replays after a restore
-        deletions, insertions = validate_edge_batch(deletions, insertions,
-                                                    self.n)
-        # a scheduled silent corruption lands on live state BEFORE the
-        # batch, so this drive's fused invariants (or the next scrub) must
-        # be what detects it — the domain's whole point
-        if self._corruption_faults is not None and not self._replaying:
-            cfault = self._corruption_faults.pop_pending()
-            if cfault is not None:
-                self._apply_corruption(cfault)
-        bidx = self._batch_index + 1
-        wal_undo = None
-        if self.store is not None and not self._replaying:
-            wal_undo = self.store.wal_size()
-        try:
-            if wal_undo is not None:
-                # write-ahead: the batch is durable BEFORE any device
-                # scatter, so a crash-stop at any instant restores to
-                # either fully-before or (via replay) fully-after this
-                # batch.  Inside the try: a failed append (torn frame on
-                # ENOSPC) must also roll back, or the broken tail would
-                # hide every later acknowledged record from read_wal
-                self.store.append_wal(
-                    batch_index=bidx, variant=variant,
-                    deletions=np.asarray(deletions,
-                                         np.int64).reshape(-1, 2),
-                    insertions=np.asarray(insertions,
-                                          np.int64).reshape(-1, 2))
-            if self._sharded:
-                res = self._update_sharded(deletions, insertions, variant)
-            elif self._walk:
-                res = self._update_walk(deletions, insertions, variant)
-            elif self._stream:
-                res = self._update_stream(deletions, insertions, variant)
-            else:
-                res = self._update_snapshot(deletions, insertions, variant)
-        except BaseException:
-            # the batch was REJECTED in-process (it never became session
-            # state): revoke its record so a later restore does not replay
-            # a batch the live session refused
-            if wal_undo is not None:
-                self.store.truncate_wal(wal_undo)
-            raise
-        self._batch_index = bidx
-        self._history.append(res)
-        if not res.stats.converged:
-            warnings.warn(
-                f"update batch {bidx} hit the sweep cap "
-                f"(max_iterations={self.config.max_iterations}) without "
-                f"reaching tau={self.config.tau} — serving the best "
-                "iterate; raise max_iterations or loosen tau "
-                "(report().sweep_cap_hits counts these)",
-                SweepCapWarning, stacklevel=2)
-        if (self._process_domain is not None and not self._replaying
-                and bidx % self._process_domain.checkpoint_interval == 0):
-            self._checkpoint_now()
-        # fused detection → repair ladder, inside the same update call (the
-        # batch itself was applied; only the iterate needs repairing)
-        if self._integrity_alert is not None and not self._replaying:
-            icfg = self.config.integrity
-            if icfg is not None and icfg.auto_repair:
-                self.verify(repair=True, deep=False)
-            # else: leave the alert posted; the next verify() handles it
-        return res
+        with TraceAnnotation("session.update"):
+            self._ensure_open()
+            if variant not in VARIANTS:
+                raise ValueError(f"variant={variant!r} invalid; "
+                                 f"expected one of {VARIANTS}")
+            if self.hg is None:
+                raise ValueError(
+                    "this session wraps a bare snapshot (from_snapshot "
+                    "without hg=); build it with PageRankSession.from_graph "
+                    "to stream updates")
+            # validate BEFORE the WAL append and before any device scatter: a
+            # NaN-weighted, duplicate, out-of-range or ambiguous batch raises
+            # here, is never durably logged, and never replays after a restore
+            with TraceAnnotation("session.validate"):
+                deletions, insertions = validate_edge_batch(
+                    deletions, insertions, self.n)
+            # a scheduled silent corruption lands on live state BEFORE the
+            # batch, so this drive's fused invariants (or the next scrub) must
+            # be what detects it — the domain's whole point
+            if self._corruption_faults is not None and not self._replaying:
+                cfault = self._corruption_faults.pop_pending()
+                if cfault is not None:
+                    self._apply_corruption(cfault)
+            bidx = self._batch_index + 1
+            wal_undo = None
+            if self.store is not None and not self._replaying:
+                wal_undo = self.store.wal_size()
+            try:
+                if wal_undo is not None:
+                    # write-ahead: the batch is durable BEFORE any device
+                    # scatter, so a crash-stop at any instant restores to
+                    # either fully-before or (via replay) fully-after this
+                    # batch.  Inside the try: a failed append (torn frame on
+                    # ENOSPC) must also roll back, or the broken tail would
+                    # hide every later acknowledged record from read_wal
+                    with TraceAnnotation("session.wal"):
+                        self.store.append_wal(
+                            batch_index=bidx, variant=variant,
+                            deletions=np.asarray(deletions,
+                                                 np.int64).reshape(-1, 2),
+                            insertions=np.asarray(insertions,
+                                                  np.int64).reshape(-1, 2))
+                if self._sharded:
+                    res = self._update_sharded(deletions, insertions, variant)
+                elif self._walk:
+                    res = self._update_walk(deletions, insertions, variant)
+                elif self._stream:
+                    res = self._update_stream(deletions, insertions, variant)
+                else:
+                    res = self._update_snapshot(deletions, insertions, variant)
+            except BaseException:
+                # the batch was REJECTED in-process (it never became session
+                # state): revoke its record so a later restore does not replay
+                # a batch the live session refused
+                if wal_undo is not None:
+                    self.store.truncate_wal(wal_undo)
+                raise
+            self._batch_index = bidx
+            # the kept record drops the [n_pad] ranks: a long-running
+            # session would otherwise hold one device array per batch
+            self._history.append(dataclasses.replace(res, ranks=None))
+            if not res.stats.converged:
+                warnings.warn(
+                    f"update batch {bidx} hit the sweep cap "
+                    f"(max_iterations={self.config.max_iterations}) without "
+                    f"reaching tau={self.config.tau} — serving the best "
+                    "iterate; raise max_iterations or loosen tau "
+                    "(report().sweep_cap_hits counts these)",
+                    SweepCapWarning, stacklevel=2)
+            if (self._process_domain is not None and not self._replaying
+                    and bidx % self._process_domain.checkpoint_interval == 0):
+                self._checkpoint_now()
+            # fused detection → repair ladder, inside the same update call (the
+            # batch itself was applied; only the iterate needs repairing)
+            if self._integrity_alert is not None and not self._replaying:
+                icfg = self.config.integrity
+                if icfg is not None and icfg.auto_repair:
+                    self.verify(repair=True, deep=False)
+                # else: leave the alert posted; the next verify() handles it
+            return res
 
     def _crossing(self, edges_rel: np.ndarray) -> int:
         """Count edges (in relabeled coordinates) whose endpoints land on
@@ -1710,34 +1723,37 @@ class PageRankSession:
             nb_active0 = _NEW_BUCKET_ACTIVE
         g_prev_snap = (self.hg.snapshot(block_size=self.block_size)
                        if variant == "dt" else None)
-        dels_eff, ins_eff = effective_batch(self.hg, deletions, insertions)
-        rows, cols, vals = signed_edge_delta(dels_eff, ins_eff)
-        if self._tiered:
-            # host tier first: patch host truth, drop residency of the
-            # touched blocks (their slab copies are stale — the admission
-            # below re-gathers them fresh), update the host aux twins.
-            # mat_prev/mat_new stay None: tiered seeding is host-side.
-            plan = self.pool.apply_delta(rows, cols, vals)
-            self.inc.aux.apply_delta(self.block_size, rows, cols, vals)
-            self.hot.invalidate(
-                plan.touched_rb,
-                structure_changed=(plan.tile_cols is not None
-                                   or plan.n_new > plan.n_old))
-            mat_prev = mat_new = None
-        else:
-            mat_prev = self.inc.mat
-            mat_new = self.inc.advance(self.hg, None, deletions, insertions,
-                                       effective=(dels_eff, ins_eff))
+        with TraceAnnotation("session.plan"):
+            dels_eff, ins_eff = effective_batch(self.hg, deletions, insertions)
+            rows, cols, vals = signed_edge_delta(dels_eff, ins_eff)
+            if self._tiered:
+                # host tier first: patch host truth, drop residency of the
+                # touched blocks (their slab copies are stale — the admission
+                # below re-gathers them fresh), update the host aux twins.
+                # mat_prev/mat_new stay None: tiered seeding is host-side.
+                plan = self.pool.apply_delta(rows, cols, vals)
+                self.inc.aux.apply_delta(self.block_size, rows, cols, vals)
+                self.hot.invalidate(
+                    plan.touched_rb,
+                    structure_changed=(plan.tile_cols is not None
+                                       or plan.n_new > plan.n_old))
+                mat_prev = mat_new = None
+            else:
+                mat_prev = self.inc.mat
+                mat_new = self.inc.advance(
+                    self.hg, None, deletions, insertions,
+                    effective=(dels_eff, ins_eff))
         self._hg_prev, self._g_prev = self.hg, None
         self._last_batch = (np.asarray(deletions, np.int64).reshape(-1, 2),
                             np.asarray(insertions, np.int64).reshape(-1, 2))
         self._r_prev = self.R
-        self.hg = self.hg.apply_batch(deletions, insertions)
-        if self.config.integrity is not None:
-            # the host-truth digest tracks every legitimate rebinding of
-            # the host graph; anything mutating hg.edges WITHOUT passing
-            # here is what the deep scrub's graph_digest check catches
-            self._hg_digest = self._graph_digest()
+        with TraceAnnotation("session.host_graph"):
+            self.hg = self.hg.apply_batch(deletions, insertions)
+            if self.config.integrity is not None:
+                # the host-truth digest tracks every legitimate rebinding of
+                # the host graph; anything mutating hg.edges WITHOUT passing
+                # here is what the deep scrub's graph_digest check catches
+                self._hg_digest = self._graph_digest()
 
         # push seeding divides by the PRE-batch degrees: capture the host
         # twin before the mirror patch below rebinds it
@@ -1746,66 +1762,69 @@ class PageRankSession:
         # bucketed signed delta crosses host→device, never the graph-sized
         # vectors
         scatter_fault, self._scatter_fault = self._scatter_fault, None
-        if len(rows):
-            b_pad = ops.capacity_bucket(len(rows), ops.DELTA_BATCH_BUCKET)
-            z = np.zeros(b_pad - len(rows), np.int32)
-            dev_args = (jnp.asarray(np.concatenate(
-                            [rows.astype(np.int32), z])),
-                        jnp.asarray(np.concatenate(
-                            [cols.astype(np.int32), z])),
-                        jnp.asarray(np.concatenate(
-                            [vals.astype(np.int32), z])))
-            # a pending torn-scatter corruption (scatter_drop/scatter_dup)
-            # silently skips or double-applies the DEVICE patch only — the
-            # host twins below stay truth, which is exactly how the scrub's
-            # mirror digests detect the tear
-            reps = {"scatter_drop": 0, "scatter_dup": 2}.get(scatter_fault, 1)
-            for _ in range(reps):
-                self._out_deg, self._rb_in, self._rb_out, self._bmat = \
-                    _apply_operand_delta(
-                        self._out_deg, self._rb_in, self._rb_out,
-                        self._bmat, *dev_args, block=self.block_size)
-            self._out_deg_host = self._out_deg_host + np.bincount(
-                cols, weights=vals, minlength=self.n_pad
-            ).astype(self._out_deg_host.dtype)
+        with TraceAnnotation("session.scatter"):
+            if len(rows):
+                b_pad = ops.capacity_bucket(len(rows), ops.DELTA_BATCH_BUCKET)
+                z = np.zeros(b_pad - len(rows), np.int32)
+                dev_args = (jnp.asarray(np.concatenate(
+                                [rows.astype(np.int32), z])),
+                            jnp.asarray(np.concatenate(
+                                [cols.astype(np.int32), z])),
+                            jnp.asarray(np.concatenate(
+                                [vals.astype(np.int32), z])))
+                # a pending torn-scatter corruption (scatter_drop/scatter_dup)
+                # silently skips or double-applies the DEVICE patch only — the
+                # host twins below stay truth, which is exactly how the scrub's
+                # mirror digests detect the tear
+                reps = {"scatter_drop": 0,
+                        "scatter_dup": 2}.get(scatter_fault, 1)
+                for _ in range(reps):
+                    self._out_deg, self._rb_in, self._rb_out, self._bmat = \
+                        _apply_operand_delta(
+                            self._out_deg, self._rb_in, self._rb_out,
+                            self._bmat, *dev_args, block=self.block_size)
+                self._out_deg_host = self._out_deg_host + np.bincount(
+                    cols, weights=vals, minlength=self.n_pad
+                ).astype(self._out_deg_host.dtype)
 
-        batch_dev = fr.pack_batch(self.n_pad, deletions, insertions)
-        seed_idx = None
-        pextras = None
-        if self._push:
-            # residual seeding replaces the frontier marking: the residual
-            # IS the frontier (work ∝ its mass).  seed_idx feeds the same
-            # tiered admission want-set as the pull df seed.
-            R0, seed_idx = self._seed_push(variant, dels_eff, ins_eff,
-                                           deg_old_host)
-            affected, expand = None, True
-        elif variant == "df":
-            if self._tiered:
-                # host-side DF seed (paper Alg. 1 lines 4-6) through the
-                # sorted host key sets — needs no device pull matrices, and
-                # only the bucketed index list crosses to the device
-                dels_a = np.asarray(deletions, np.int64).reshape(-1, 2)
-                ins_a = np.asarray(insertions, np.int64).reshape(-1, 2)
-                sources = np.concatenate([dels_a[:, 0], ins_a[:, 0]])
-                seed_idx = dist.df_seed_indices(self._hg_prev, self.hg,
-                                                sources)
-                affected = self._mask_from_indices(seed_idx)
-            else:
-                affected = _seed_affected(
-                    mat_prev, mat_new, self._bmat, batch_dev, self.valid,
-                    block_size=self.block_size, interpret=self.interpret,
-                    backend=self.backend)
-            R0, expand = self.R, True
-        elif variant == "dt":
-            g_new_snap = self.hg.snapshot(block_size=self.block_size)
-            affected = fr.dt_affected(g_prev_snap, g_new_snap, batch_dev)
-            R0, expand = self.R, False
-        elif variant == "nd":
-            affected, R0, expand = self.valid, self.R, False
-        else:   # static
-            affected = self.valid
-            R0 = jnp.where(self.valid, 1.0 / self.n, 0).astype(self._dtype)
-            expand = False
+        with TraceAnnotation("session.seed"):
+            batch_dev = fr.pack_batch(self.n_pad, deletions, insertions)
+            seed_idx = None
+            pextras = None
+            if self._push:
+                # residual seeding replaces the frontier marking: the residual
+                # IS the frontier (work ∝ its mass).  seed_idx feeds the same
+                # tiered admission want-set as the pull df seed.
+                R0, seed_idx = self._seed_push(variant, dels_eff, ins_eff,
+                                               deg_old_host)
+                affected, expand = None, True
+            elif variant == "df":
+                if self._tiered:
+                    # host-side DF seed (paper Alg. 1 lines 4-6) through the
+                    # sorted host key sets — needs no device pull matrices, and
+                    # only the bucketed index list crosses to the device
+                    dels_a = np.asarray(deletions, np.int64).reshape(-1, 2)
+                    ins_a = np.asarray(insertions, np.int64).reshape(-1, 2)
+                    sources = np.concatenate([dels_a[:, 0], ins_a[:, 0]])
+                    seed_idx = dist.df_seed_indices(self._hg_prev, self.hg,
+                                                    sources)
+                    affected = self._mask_from_indices(seed_idx)
+                else:
+                    affected = _seed_affected(
+                        mat_prev, mat_new, self._bmat, batch_dev, self.valid,
+                        block_size=self.block_size, interpret=self.interpret,
+                        backend=self.backend)
+                R0, expand = self.R, True
+            elif variant == "dt":
+                g_new_snap = self.hg.snapshot(block_size=self.block_size)
+                affected = fr.dt_affected(g_prev_snap, g_new_snap, batch_dev)
+                R0, expand = self.R, False
+            elif variant == "nd":
+                affected, R0, expand = self.valid, self.R, False
+            else:   # static
+                affected = self.valid
+                R0 = jnp.where(self.valid, 1.0 / self.n, 0).astype(self._dtype)
+                expand = False
         if self._tiered:
             # tiered drives always expand: the refill loop is block-Jacobi
             # over residency partitions, and only frontier expansion

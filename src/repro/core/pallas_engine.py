@@ -93,6 +93,10 @@ def _driver(mat: ops.BlockSparse, R0, affected0, valid, out_deg,
     matrix is capacity-padded; the degree/adjacency vectors are per-block,
     the grid is fixed), so a stream re-enters one compiled trace.
 
+    A sweep's three phases run under ``jax.named_scope`` (``df.sweep``,
+    ``df.expand``, ``df.account``), which names them in the ops' metadata
+    and changes nothing that runs.
+
     ``tiered=True`` (tiered storage, :mod:`repro.core.tiering`): ``mat`` is
     the device *hot-slab view* and ``rb_res`` marks which row-blocks are
     resident.  A non-resident block is never swept — seeds landing in it and
@@ -156,71 +160,74 @@ def _driver(mat: ops.BlockSparse, R0, affected0, valid, out_deg,
 
         # -- compacted frontier sweep: pull over active row-blocks only,
         #    launched at the smallest ladder bucket ≥ |active| -------------
-        ids = jnp.where(do, fr.compact_block_ids(act_rb, n_rb), -1)
-        n_eff = jnp.where(do, n_act, 0)
-        pulled = ops.block_spmv_active_bucketed(
-            mat, R * inv_deg, ids, n_eff, semiring="sum",
-            interpret=interpret, backend=backend, ladder=ladder)
-        r_new = base + alpha_c * pulled
-        act_v = jnp.repeat(act_rb, B)
-        upd = affected & act_v & valid & do
-        r_fin = jnp.where(upd, r_new, R)
-        dr = jnp.where(upd, jnp.abs(r_fin - R), 0)
-        maxdr = dr.max()
-        RC1 = jnp.where(upd, dr > tau_c, RC)
+        with jax.named_scope("df.sweep"):
+            ids = jnp.where(do, fr.compact_block_ids(act_rb, n_rb), -1)
+            n_eff = jnp.where(do, n_act, 0)
+            pulled = ops.block_spmv_active_bucketed(
+                mat, R * inv_deg, ids, n_eff, semiring="sum",
+                interpret=interpret, backend=backend, ladder=ladder)
+            r_new = base + alpha_c * pulled
+            act_v = jnp.repeat(act_rb, B)
+            upd = affected & act_v & valid & do
+            r_fin = jnp.where(upd, r_new, R)
+            dr = jnp.where(upd, jnp.abs(r_fin - R), 0)
+            maxdr = dr.max()
+            RC1 = jnp.where(upd, dr > tau_c, RC)
 
         # -- DF expansion: OR semiring over candidate row-blocks ------------
-        if expand:
-            changed = upd & (dr > tau_f_c)
-            ch_cb = fr.block_any(changed, n_rb, B)
-            cand_rb = (bmat & ch_cb[None, :]).any(axis=1)
-            if tiered:
-                # candidate blocks not on device: defer (re-mark for the
-                # next drive after admission) instead of syncing mid-sweep
-                deferred = deferred | (cand_rb & ~rb_res & do)
-                cand_rb = cand_rb & rb_res
-            n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
-            cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
-            hitf = ops.block_spmv_active_bucketed(
-                mat, changed.astype(dtype), cids, n_cand, semiring="or",
-                interpret=interpret, backend=backend, ladder=ladder)
-            hit = (hitf > 0) & jnp.repeat(cand_rb, B) & valid & do
-            affected1 = affected | hit
-            RC1 = RC1 | hit
-            out_rb = jnp.where(ch_cb, rb_out, 0)
-        else:
-            affected1 = affected
-            ch_cb = jnp.zeros((n_rb,), bool)
-            out_rb = jnp.zeros((n_rb,), rb_out.dtype)
+        with jax.named_scope("df.expand"):
+            if expand:
+                changed = upd & (dr > tau_f_c)
+                ch_cb = fr.block_any(changed, n_rb, B)
+                cand_rb = (bmat & ch_cb[None, :]).any(axis=1)
+                if tiered:
+                    # candidate blocks not on device: defer (re-mark for the
+                    # next drive after admission) instead of syncing mid-sweep
+                    deferred = deferred | (cand_rb & ~rb_res & do)
+                    cand_rb = cand_rb & rb_res
+                n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
+                cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
+                hitf = ops.block_spmv_active_bucketed(
+                    mat, changed.astype(dtype), cids, n_cand, semiring="or",
+                    interpret=interpret, backend=backend, ladder=ladder)
+                hit = (hitf > 0) & jnp.repeat(cand_rb, B) & valid & do
+                affected1 = affected | hit
+                RC1 = RC1 | hit
+                out_rb = jnp.where(ch_cb, rb_out, 0)
+            else:
+                affected1 = affected
+                ch_cb = jnp.zeros((n_rb,), bool)
+                out_rb = jnp.zeros((n_rb,), rb_out.dtype)
 
         # -- work accounting + fault-time model (paper §5.1.6) --------------
-        in_rb = jnp.where(act_rb, rb_in, 0)
-        e_sweep = jnp.where(do, (in_rb + out_rb).astype(cdt).sum(), 0)
-        ids_c = jnp.maximum(ids, 0)
-        real_slot = ids >= 0
-        slot_edges = jnp.where(
-            real_slot,
-            rb_in[ids_c] + jnp.where(ch_cb[ids_c], rb_out[ids_c], 0),
-            0).astype(jnp.float32)
-        pid = jnp.nonzero(participate, size=n_threads,
-                          fill_value=0)[0].astype(jnp.int32)
-        w = participate.sum(dtype=jnp.int32)
-        tid = pid[jnp.arange(n_rb, dtype=jnp.int32) % jnp.maximum(w, 1)]
-        th_edges = jax.ops.segment_sum(slot_edges, tid,
-                                       num_segments=n_threads)
-        th_blocks = jax.ops.segment_sum(real_slot.astype(jnp.float32), tid,
-                                        num_segments=n_threads)
-        work_ms = (th_edges * flt.T_EDGE_NS
-                   + th_blocks * flt.T_BLOCK_NS) * 1e-6
-        delay_row = delay_table[it]
-        alive = alive_table[it]
-        if jacobi:
-            step_ms = jnp.max(work_ms + delay_row)
-        else:
-            step_ms = jnp.where(
-                asleep, jnp.max(jnp.where(alive, delay_row, 0)),
-                jnp.max(jnp.where(alive, work_ms, 0)))
-        step_ms = jnp.where(do | asleep, step_ms, 0.0)
+        with jax.named_scope("df.account"):
+            in_rb = jnp.where(act_rb, rb_in, 0)
+            e_sweep = jnp.where(do, (in_rb + out_rb).astype(cdt).sum(), 0)
+            ids_c = jnp.maximum(ids, 0)
+            real_slot = ids >= 0
+            slot_edges = jnp.where(
+                real_slot,
+                rb_in[ids_c] + jnp.where(ch_cb[ids_c], rb_out[ids_c], 0),
+                0).astype(jnp.float32)
+            pid = jnp.nonzero(participate, size=n_threads,
+                              fill_value=0)[0].astype(jnp.int32)
+            w = participate.sum(dtype=jnp.int32)
+            tid = pid[jnp.arange(n_rb, dtype=jnp.int32) % jnp.maximum(w, 1)]
+            th_edges = jax.ops.segment_sum(slot_edges, tid,
+                                           num_segments=n_threads)
+            th_blocks = jax.ops.segment_sum(real_slot.astype(jnp.float32), tid,
+                                            num_segments=n_threads)
+            work_ms = (th_edges * flt.T_EDGE_NS
+                       + th_blocks * flt.T_BLOCK_NS) * 1e-6
+            delay_row = delay_table[it]
+            alive = alive_table[it]
+            if jacobi:
+                step_ms = jnp.max(work_ms + delay_row)
+            else:
+                step_ms = jnp.where(
+                    asleep, jnp.max(jnp.where(alive, delay_row, 0)),
+                    jnp.max(jnp.where(alive, work_ms, 0)))
+            step_ms = jnp.where(do | asleep, step_ms, 0.0)
 
         # -- convergence ----------------------------------------------------
         if jacobi:

@@ -87,7 +87,9 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
     ``P0`` is the rank estimate and ``R0`` the residual satisfying
     ``r = b + M·p − p`` (the caller maintains it via seeding or full
     recompute).  Operand shapes are stable across a stream — same
-    zero-retrace contract as the pull driver.
+    zero-retrace contract as the pull driver.  A sweep's phases carry the
+    pull driver's scope names: ``df.expand`` (residual scan, source and
+    candidate selection), ``df.sweep`` (the push) and ``df.account``.
 
     ``tiered=True``: ``rb_res`` marks resident row-blocks.  Pushes deliver
     to resident candidate destination rows only; a pushed-to non-resident
@@ -119,81 +121,89 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
 
     def body(state):
         P, Rr, it, converged, stalled, deferred, ctr = state
-        aRr = jnp.abs(Rr).reshape(n_rb, B)
-        rb_mass = aRr.sum(axis=1)
-        rb_maxr = aRr.max(axis=1)
-        maxr = rb_maxr.max()
-        # ulp-floor escape (PR-9 maxdr analogue): every remaining residual
-        # is below the rounding granularity of p — pushing cannot move p
-        at_floor = maxr <= 16.0 * eps * jnp.maximum(jnp.abs(P).max(),
-                                                    base_floor)
-        # per-vertex exit: pushing v moves p[v] by exactly r[v], so
-        # max|r| ≤ tau is the same strength as the pull driver's
-        # maxdr ≤ tau stop — no vertex's next move would exceed tau
-        conv_now = (maxr <= tau_c) | at_floor
-        pushable = rb_maxr > tau_c
-        n_push = pushable.sum(dtype=jnp.int32)
-        do = ~conv_now & (n_push > 0)
-        # defensive only: maxr > tau with every per-block max ≤ tau is
-        # impossible (maxr IS the max over the per-block maxima)
-        stall_now = ~conv_now & (n_push == 0)
+        with jax.named_scope("df.expand"):
+            aRr = jnp.abs(Rr).reshape(n_rb, B)
+            rb_mass = aRr.sum(axis=1)
+            rb_maxr = aRr.max(axis=1)
+            maxr = rb_maxr.max()
+            # ulp-floor escape (PR-9 maxdr analogue): every remaining
+            # residual is below the rounding granularity of p — pushing
+            # cannot move p
+            at_floor = maxr <= 16.0 * eps * jnp.maximum(jnp.abs(P).max(),
+                                                        base_floor)
+            # per-vertex exit: pushing v moves p[v] by exactly r[v], so
+            # max|r| ≤ tau is the same strength as the pull driver's
+            # maxdr ≤ tau stop — no vertex's next move would exceed tau
+            conv_now = (maxr <= tau_c) | at_floor
+            pushable = rb_maxr > tau_c
+            n_push = pushable.sum(dtype=jnp.int32)
+            do = ~conv_now & (n_push > 0)
+            # defensive only: maxr > tau with every per-block max ≤ tau is
+            # impossible (maxr IS the max over the per-block maxima)
+            stall_now = ~conv_now & (n_push == 0)
 
-        # -- bucketed top-mass source selection: smallest ladder bucket
-        #    K ≥ |pushable|, top-K blocks by residual mass via lax.switch.
-        #    K ≥ |pushable| makes selection complete; the bucket bounds the
-        #    top-k cost and keeps the trace static (retrace-free). --------
-        mass_m = jnp.where(pushable, rb_mass, -1.0)
+            # -- bucketed top-mass source selection: smallest ladder
+            #    bucket K ≥ |pushable|, top-K blocks by residual mass via
+            #    lax.switch.  K ≥ |pushable| makes selection complete; the
+            #    bucket bounds the top-k cost and keeps the trace static
+            #    (retrace-free). ---------------------------------------------
+            mass_m = jnp.where(pushable, rb_mass, -1.0)
 
-        def sel_at(K):
-            vals, ids = lax.top_k(mass_m, K)
-            keep = vals > 0
-            sel_p = jnp.zeros((n_rb + 1,), bool)
-            sel_p = sel_p.at[jnp.where(keep, ids, n_rb)].set(True)
-            return sel_p[:n_rb]
+            def sel_at(K):
+                vals, ids = lax.top_k(mass_m, K)
+                keep = vals > 0
+                sel_p = jnp.zeros((n_rb + 1,), bool)
+                sel_p = sel_p.at[jnp.where(keep, ids, n_rb)].set(True)
+                return sel_p[:n_rb]
 
-        if len(ladder) == 1:
-            sel = sel_at(ladder[0])
-        else:
-            branches = [partial(sel_at, K) for K in ladder]
-            bidx = sum((n_push > K).astype(jnp.int32)
-                       for K in ladder[:-1])
-            sel = lax.switch(bidx, branches)
-        sel = sel & do
+            if len(ladder) == 1:
+                sel = sel_at(ladder[0])
+            else:
+                branches = [partial(sel_at, K) for K in ladder]
+                bidx = sum((n_push > K).astype(jnp.int32)
+                           for K in ladder[:-1])
+                sel = lax.switch(bidx, branches)
+            sel = sel & do
+
+            # candidate destination row-blocks of the selected sources
+            cand = (bmat & sel[None, :]).any(axis=1)
+            if tiered:
+                # deliver to resident destination rows only; a pushed-to
+                # non-resident row goes stale → deferred bitmap (the refill
+                # loop admits it and recomputes r = b + M·p − p exactly —
+                # never a mid-sweep sync).  sel is already zero on converged
+                # iterations, so cand carries the ~conv gate.
+                deferred = deferred | (cand & ~rb_res)
+                cand_rb = cand & rb_res
+            else:
+                cand_rb = cand
+            n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
+            cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
 
         # -- the push: scatter-semiring SpMV over candidate dst blocks.
         #    Per-vertex threshold (Andersen–Chung–Lang form): only entries
         #    with |r| > tau move — sub-tau entries stay in r, which is
         #    exactly what the max|r| ≤ tau exit permits — so edge work is
-        #    Σ out-deg over *pushed vertices*, not over whole blocks. ------
-        sel_v = jnp.repeat(sel, B) & valid & (jnp.abs(Rr) > tau_c)
-        cand = (bmat & sel[None, :]).any(axis=1)
-        if tiered:
-            # deliver to resident destination rows only; a pushed-to
-            # non-resident row goes stale → deferred bitmap (the refill
-            # loop admits it and recomputes r = b + M·p − p exactly —
-            # never a mid-sweep sync).  sel is already zero on converged
-            # iterations, so cand carries the ~conv gate.
-            deferred = deferred | (cand & ~rb_res)
-            cand_rb = cand & rb_res
-        else:
-            cand_rb = cand
-        n_cand = jnp.where(do, cand_rb.sum(dtype=jnp.int32), 0)
-        cids = jnp.where(do, fr.compact_block_ids(cand_rb, n_rb), -1)
-        moved = jnp.where(sel_v, Rr, 0)
-        pushed = ops.block_spmv_push_bucketed(
-            mat, moved * inv_deg, sel, cids, n_cand,
-            interpret=interpret, backend=backend, ladder=ladder)
-        pushed = jnp.where(jnp.repeat(cand_rb, B) & valid & do, pushed, 0)
-        P1 = P + moved
-        R1 = Rr - moved + alpha_c * pushed
+        #    Σ out-deg over *pushed vertices*, not over whole blocks. --------
+        with jax.named_scope("df.sweep"):
+            sel_v = jnp.repeat(sel, B) & valid & (jnp.abs(Rr) > tau_c)
+            moved = jnp.where(sel_v, Rr, 0)
+            pushed = ops.block_spmv_push_bucketed(
+                mat, moved * inv_deg, sel, cids, n_cand,
+                interpret=interpret, backend=backend, ladder=ladder)
+            pushed = jnp.where(jnp.repeat(cand_rb, B) & valid & do, pushed,
+                               0)
+            P1 = P + moved
+            R1 = Rr - moved + alpha_c * pushed
 
-        sweeps, pushed_b, cand_b, edges = ctr
-        # edge work = out-edges of the vertices actually pushed this sweep
-        e_sweep = jnp.where(sel_v, out_deg, 0).astype(cdt).sum()
-        ctr1 = (sweeps + jnp.where(do, 1, 0).astype(cdt),
-                pushed_b + jnp.where(do, n_push, 0).astype(cdt),
-                cand_b + n_cand.astype(cdt),
-                edges + e_sweep)
+        with jax.named_scope("df.account"):
+            sweeps, pushed_b, cand_b, edges = ctr
+            # edge work = out-edges of the vertices pushed this sweep
+            e_sweep = jnp.where(sel_v, out_deg, 0).astype(cdt).sum()
+            ctr1 = (sweeps + jnp.where(do, 1, 0).astype(cdt),
+                    pushed_b + jnp.where(do, n_push, 0).astype(cdt),
+                    cand_b + n_cand.astype(cdt),
+                    edges + e_sweep)
         return (P1, R1, it + 1, converged | conv_now,
                 stalled | stall_now, deferred, ctr1)
 

@@ -576,7 +576,9 @@ def block_spmv_active_bucketed(mat: BlockSparse, x: jnp.ndarray,
     the K-slot kernel on ``active_ids[:K]`` — so the Pallas grid / the XLA
     gather scales with the actual frontier, not ``n_rb``.  Trace-safe inside
     the fused driver's ``while_loop`` (the switch index is a traced scalar;
-    every branch has static shapes).  O(log n_rb) branches are compiled once.
+    every branch has static shapes).  O(log n_rb) branches are compiled once;
+    each runs under ``jax.named_scope("spmv.<semiring>.k<K>")``, so a trace's
+    op metadata tells the buckets and semirings apart.
     On the Pallas backend a bucket whose slot tables exceed the SMEM
     prefetch budget runs as several launches
     (:func:`repro.kernels.block_spmv.block_spmv.launch_rows`).
@@ -599,11 +601,16 @@ def block_spmv_active_bucketed(mat: BlockSparse, x: jnp.ndarray,
             block=mat.block, max_tiles=mat.max_tiles, semiring=semiring,
             interpret=interpret)
 
+    def run_at(K):
+        # the scope names the bucket in the ops' metadata; the kernel's
+        # own instruction name is left as the compiler gives it
+        with jax.named_scope(f"spmv.{semiring}.k{K}"):
+            return run(ids32[:K])
+
     if len(lad) == 1:
-        y = run(ids32[:lad[0]])
+        y = run_at(lad[0])
     else:
-        branches = [functools.partial(lambda K: run(ids32[:K]), K)
-                    for K in lad]
+        branches = [functools.partial(run_at, K) for K in lad]
         bidx = sum((n_active > K).astype(jnp.int32) for K in lad[:-1])
         y = lax.switch(bidx, branches)
     return y[:mat.n_rows]
@@ -630,11 +637,13 @@ def block_spmv_push_bucketed(mat: BlockSparse, x: jnp.ndarray,
 
     Same output contract as :func:`block_spmv_active_bucketed`: rows of
     blocks outside ``active_ids`` are UNDEFINED on the Pallas backend —
-    mask with the candidate indicator before consuming."""
+    mask with the candidate indicator before consuming.  Its launches run
+    under ``jax.named_scope("spmv.push")``, above the bucket scopes."""
     xm = jnp.where(jnp.repeat(src_cb, mat.block)[:x.shape[0]], x, 0)
-    return block_spmv_active_bucketed(
-        mat, xm, active_ids, n_active, semiring="sum",
-        interpret=interpret, backend=backend, ladder=ladder)
+    with jax.named_scope("spmv.push"):
+        return block_spmv_active_bucketed(
+            mat, xm, active_ids, n_active, semiring="sum",
+            interpret=interpret, backend=backend, ladder=ladder)
 
 
 def block_adjacency(mat: BlockSparse) -> jnp.ndarray:

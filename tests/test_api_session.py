@@ -2,10 +2,12 @@
 shims (warning + bit-for-bit routing parity), fork semantics, and the
 multi-session service."""
 import dataclasses
+import gc
 import warnings
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.api import (EngineConfig, PageRankService, PageRankSession,
@@ -305,6 +307,31 @@ class TestSessionLifecycle:
         res = sess.update(d3, i3)       # concurrent first-visit drive
         assert res.driver_retraces == 0  # explains the growth
         assert res.bucket_retraces == 1
+
+    def test_history_holds_no_device_array_per_update(self, dyn):
+        """The session keeps each update's stats, not its ranks: the device
+        arrays alive stay as many over a long stream."""
+        hg = dyn[0]
+        sess = PageRankSession.from_graph(
+            hg, config=EngineConfig(engine="pallas", block_size=64))
+        sess.warmup()
+        batches = []
+        for i in range(22):
+            dels, ins = random_batch(hg, 2e-3, seed=100 + i)
+            batches.append((dels, ins))
+            hg = hg.apply_batch(dels, ins)
+        for dels, ins in batches[:2]:
+            sess.update(dels, ins)
+        gc.collect()
+        alive = len(jax.live_arrays())
+        for dels, ins in batches[2:]:
+            assert sess.update(dels, ins).ranks is not None
+        gc.collect()
+        assert len(jax.live_arrays()) <= alive
+        rep = sess.report()
+        assert rep.n_updates == 22 and len(rep.sweeps_history) == 22
+        assert sess._history[-1].ranks is None
+        sess.close()
 
     def test_fork_branches_are_independent(self, dyn):
         hg0, _, _, _, _, r_prev, dels, ins = dyn

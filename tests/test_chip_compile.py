@@ -85,10 +85,12 @@ def test_kernel_compiles_at_1m(one_chip, kernel, semiring):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pull_driver_compiles_at_1m(one_chip):
+@pytest.fixture(scope="module")
+def pull_driver_hlo(one_chip):
+    """Text of the fused pull driver compiled at the 1M layout."""
     S = _spec(one_chip)
     f32, b = jnp.float32, jnp.bool_
-    compiled = pe._driver.lower(
+    return pe._driver.lower(
         _mat(S), S((N_PAD,), f32), S((N_PAD,), b), S((N_PAD,), b),
         S((N_PAD,), jnp.int32), S((N_RB,), jnp.int32),
         S((N_RB,), jnp.int32), S((N_RB, N_RB), b), S((N_RB,), b),
@@ -97,8 +99,31 @@ def test_pull_driver_compiles_at_1m(one_chip):
         S((MAX_ITERATIONS, 1), f32), S((MAX_ITERATIONS,), b),
         n=N_PAD, block_size=B, mode="lf", expand=True,
         active_policy="affected", max_iterations=MAX_ITERATIONS,
-        interpret=False, backend="pallas").compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        interpret=False, backend="pallas").compile().as_text()
+
+
+def test_pull_driver_compiles_at_1m(pull_driver_hlo):
+    assert "tpu_custom_call" in pull_driver_hlo
+
+
+def test_pull_driver_scopes_leave_instruction_names(pull_driver_hlo):
+    """The pull driver's named scopes reach the kernel launches' op
+    metadata only: the launches stay ``tpu_custom_call`` custom calls, and no
+    custom-call instruction takes a scope's name (a device trace names
+    ops by instruction, and the benchmark's readers match those names)."""
+    calls = [ln.split(" = ", 1) for ln in pull_driver_hlo.splitlines()
+             if " custom-call(" in ln]
+    kernels = [rhs for _, rhs in calls
+               if 'custom_call_target="tpu_custom_call"' in rhs]
+    assert kernels
+    for lhs, _ in calls:
+        name = lhs.strip().lstrip("%")
+        assert not name.startswith(("spmv.", "df.", "delta.")), name
+    scoped = {sem: any(f"/spmv.{sem}.k" in rhs for rhs in kernels)
+              for sem in ("sum", "or")}
+    assert scoped == {"sum": True, "or": True}
+    assert any("/df.sweep/" in rhs for rhs in kernels)
+    assert any("/df.expand/" in rhs for rhs in kernels)
 
 
 def test_push_driver_compiles_at_1m(one_chip):
