@@ -94,7 +94,7 @@ class UpdateRequest:
     started_s: float = 0.0
     done_s: float = 0.0
     deadline_at_s: Optional[float] = None  # absolute (perf_counter) deadline
-    result: Optional[StreamBatchResult] = None
+    result: Optional[StreamBatchResult] = None   # its ranks are None
     done: bool = False
     attempts: int = 0             # dispatch attempts consumed (retries + 1)
     deadline_missed: bool = False  # completed after its deadline
@@ -536,8 +536,12 @@ class PageRankService:
                         # to the orphaned pre-failover session; retiring it too
                         # would double-apply, so abandon it
                         return True
+                    # a finished request keeps the update's record, not
+                    # its [n_pad] ranks: the queue of finished requests
+                    # grows with dispatches, the device memory must not
+                    kept = dataclasses.replace(result, ranks=None)
                     for req in reqs:
-                        req.result = result
+                        req.result = kept
                         req.done_s = done
                         req.done = True
                         if (req.deadline_at_s is not None

@@ -392,6 +392,34 @@ class TestService:
             assert pr.linf(svc.sessions[i].R[:n],
                            jnp.asarray(ref[:n])) < 1e-8
 
+    def test_finished_requests_hold_no_rank_vector(self):
+        """A finished request keeps its update's record without the [n_pad]
+        ranks: the device arrays alive stay as many however many
+        dispatches the service has finished."""
+        hg = rmat(8, avg_degree=4, seed=3)
+        svc = PageRankService(
+            [hg], config=EngineConfig(engine="pallas", block_size=64),
+            serving=ServingConfig(coalesce=False))
+        batches = []
+        for i in range(12):
+            dels, ins = random_batch(hg, 1e-2, seed=300 + i)
+            batches.append((dels, ins))
+            hg = hg.apply_batch(dels, ins)
+        for dels, ins in batches[:2]:
+            svc.submit(0, dels, ins)
+        svc.run_until_drained()
+        gc.collect()
+        alive = len(jax.live_arrays())
+        for dels, ins in batches[2:]:
+            svc.submit(0, dels, ins)
+        done = svc.run_until_drained()
+        gc.collect()
+        assert len(jax.live_arrays()) <= alive
+        assert len(done) == 12
+        assert all(r.result.ranks is None and r.result.stats.converged
+                   for r in done)
+        assert svc.sessions[0].report().n_updates == 12
+
     def test_step_coalesces_queue_into_one_update(self):
         hg = rmat(8, avg_degree=4, seed=2)
         svc = PageRankService(

@@ -6,9 +6,11 @@ what interpret mode cannot — scalar-memory (SMEM) overflow of the
 prefetched slot tables, index maps that trace to int64 under x64, block
 shapes the chip refuses — at the n = 1,048,576 layout of
 ``grid_road(1024)``: 16,384 row-blocks of B = 64, 16 tile slots per row,
-float32 ranks.  The suite enables x64, so these compiles also guard the
-kernels against it.  One more case lowers a kernel from two checkouts and
-checks that the scripts' cache settings give both the same cache key.
+float32 ranks, and the kernel also at the ``rmat-s15`` layout of 512
+row-blocks of 512 slots.  The suite enables x64, so these compiles also
+guard the kernels against it.  One more case lowers a kernel from two
+checkouts and checks that the scripts' cache settings give both the same
+cache key.
 
 The topology is described inside a module-scoped fixture (never at
 import): only one process may load the TPU library at a time, so only the
@@ -82,6 +84,22 @@ def test_kernel_compiles_at_1m(one_chip, kernel, semiring):
         lowered = bk.block_spmv_active_pallas.lower(
             S((N_RB,), jnp.int32), *args, **kw)
     compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("semiring", ["sum", "or"])
+def test_active_kernel_compiles_at_rmat_s15(one_chip, semiring):
+    """The ``rmat-s15`` layout: 512 row-blocks of 512 slots each (32,768
+    vertices, a 262,144-tile pool), every row-block active — a launch of
+    wide rows whose slot tables split into several launches."""
+    S = _spec(one_chip)
+    n_rb, mt, cap = 512, 512, 262_144
+    compiled = bk.block_spmv_active_pallas.lower(
+        S((n_rb,), jnp.int32), S((n_rb * mt,), jnp.int32),
+        S((n_rb, mt), jnp.int32), S((cap,) + tile_shape(B), jnp.float32),
+        S((n_rb * B,), jnp.float32), block=B, max_tiles=mt,
+        semiring=semiring).compile()
+    assert bk.launch_rows(mt) < n_rb
     assert "tpu_custom_call" in compiled.as_text()
 
 

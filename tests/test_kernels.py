@@ -181,6 +181,136 @@ def test_chunked_launches_match_xla_and_full_kernel(semiring):
         np.testing.assert_allclose(y[on], full[on], rtol=1e-6, atol=1e-6)
 
 
+def _rows_of_widths(widths, block, n_cb, seed):
+    """Edges whose row-block i owns tiles in ``widths[i]`` distinct column
+    blocks, a few random entries each; returns (rows, cols, values)."""
+    rng = np.random.default_rng(seed)
+    rows, cols = [], []
+    for rb, w in enumerate(widths):
+        for cb in rng.choice(n_cb, w, replace=False):
+            k = int(rng.integers(1, 4))
+            rows.append(rb * block + rng.integers(0, block, k))
+            cols.append(cb * block + rng.integers(0, block, k))
+    rows = np.concatenate(rows)
+    cols = np.concatenate(cols)
+    return rows, cols, rng.random(len(rows)).astype(np.float32)
+
+
+# row-block widths: empty rows, rows full to max_tiles (6), mixed widths
+WIDTHS = [0, 6, 3, 0, 1, 6, 2, 5, 0, 4, 6, 1]
+
+
+@pytest.mark.parametrize("block", [8, 64])
+@pytest.mark.parametrize("semiring", ["sum", "or"])
+@pytest.mark.parametrize("case", ["widths", "ladder_pad", "ragged_step",
+                                  "launches"])
+def test_live_tile_walk_matches_xla_and_dense(case, semiring, block):
+    """The kernel walks each row-block's live slots only: against the XLA
+    tile path and a dense reference, with empty and full rows, -1 ladder
+    padding, a launch whose K is no multiple of the rows per step, and
+    several launches forced by a small SMEM budget."""
+    from repro.kernels.block_spmv import block_spmv as bk
+    from repro.kernels.block_spmv.ops import _block_spmv_active_xla
+    n_rb = n_cb = len(WIDTHS)
+    n = n_rb * block
+    rows, cols, vals = _rows_of_widths(WIDTHS, block, n_cb, seed=block)
+    mat = build_block_sparse(rows, cols, n, n, block=block, values=vals)
+    mt = mat.max_tiles
+    assert mt == max(WIDTHS)
+    rng = np.random.default_rng(31)
+    x = rng.random(n)
+    if semiring == "or":
+        x = x < 0.3
+    x = jnp.asarray(x, jnp.float32)
+    budget = bk.SMEM_PREFETCH_BUDGET
+    if case == "widths":                  # every row-block, in order
+        ids = np.arange(n_rb)
+    elif case == "ladder_pad":            # a bucket of 16: 7 ids, 9 x -1
+        ids = np.full(16, -1)
+        ids[:7] = rng.permutation(n_rb)[:7]
+    elif case == "ragged_step":           # K = 11: no multiple of 8 rows
+        ids = rng.permutation(n_rb)[:11]
+        assert 11 % bk.rows_per_step(mt, 11) != 0
+    else:                                 # launches of 2 row-blocks
+        ids = np.concatenate([rng.permutation(n_rb), [-1, -1, -1]])
+        budget = 3 * 2 * mt * 4           # tables of 2 rows fit, of 4 not
+        assert bk.launch_rows(mt, budget) == 2
+    ids = jnp.asarray(ids, jnp.int32)
+    args = (mat.tile_idx, mat.tile_cols, mat.tiles, x)
+    y = np.asarray(bk.block_spmv_active_pallas(
+        ids, *args, block=block, max_tiles=mt, semiring=semiring,
+        interpret=True, smem_budget=budget))
+    ref = np.asarray(_block_spmv_active_xla(ids, *args, block=block,
+                                            max_tiles=mt, semiring=semiring))
+    dense = np.zeros((n, n))
+    np.add.at(dense, (rows, cols), vals.astype(np.float64))
+    want = dense @ np.asarray(x, np.float64)
+    if semiring == "or":
+        want = (want > 0).astype(np.float64)
+    act = np.asarray(ids)
+    on = np.repeat(np.isin(np.arange(n_rb), act[act >= 0]), block)
+    np.testing.assert_allclose(y[on], ref[on], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y[on], want[on], rtol=1e-5, atol=1e-6)
+
+
+def test_live_slots_stay_a_prefix_across_deltas():
+    """The kernel stops a row-block's walk at its first -1 slot, so the
+    live slots of every row must stay a prefix of its slot row after
+    insert and delete batches (new tiles, emptied tiles, a widened
+    table), and the kernel must still match the XLA path."""
+    from repro.kernels.block_spmv.ops import apply_delta
+    n, B = 2048, 32
+    rng = np.random.default_rng(41)
+    rows, cols = _random_edges(n, n, 300, seed=42)
+    mat = build_block_sparse(rows, cols, n, n, block=B, padded=True)
+    live_edges = [(int(r), int(c)) for r, c in zip(rows, cols)]
+    widths = []
+    for step in range(6):
+        ins_r, ins_c = _random_edges(n, n, 20 + 30 * (step % 2),
+                                     seed=100 + step)
+        if step == 3:                 # crowd one row-block past its width
+            ins_r = np.concatenate([ins_r, np.full(40, 5 * B)])
+            ins_c = np.concatenate([ins_c, np.arange(40) * B])
+        pick = rng.choice(len(live_edges), 30, replace=False)
+        del_r = np.array([live_edges[i][0] for i in pick])
+        del_c = np.array([live_edges[i][1] for i in pick])
+        live_edges = [e for i, e in enumerate(live_edges)
+                      if i not in set(pick.tolist())]
+        live_edges += list(zip(ins_r.tolist(), ins_c.tolist()))
+        mat = apply_delta(mat, np.concatenate([ins_r, del_r]),
+                          np.concatenate([ins_c, del_c]),
+                          np.concatenate([np.ones(len(ins_r)),
+                                          -np.ones(len(del_r))]))
+        occ = np.asarray(mat.tile_cols) >= 0
+        assert not (occ[:, 1:] & ~occ[:, :-1]).any(), f"hole after {step}"
+        widths.append(mat.max_tiles)
+    assert widths[-1] > widths[0]             # the table widened once
+    x = jnp.asarray(rng.random(n), jnp.float32)
+    r_all, c_all = (np.array(v) for v in zip(*live_edges))
+    np.testing.assert_allclose(
+        np.asarray(block_spmv(mat, x, interpret=True, backend="pallas")),
+        np.asarray(spmv_ref(r_all, c_all, n, x)), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("entry", ["full", "active"])
+def test_unknown_semiring_rejected(entry):
+    """Only "sum" and "or" exist: any other name fails before a launch
+    instead of running the OR walk."""
+    from repro.kernels.block_spmv import block_spmv as bk
+    rows, cols = _random_edges(64, 64, 100, seed=51)
+    mat = build_block_sparse(rows, cols, 64, 64, block=8)
+    args = (mat.tile_idx, mat.tile_cols, mat.tiles,
+            jnp.ones(64, jnp.float32))
+    kw = dict(block=8, max_tiles=mat.max_tiles, semiring="max",
+              interpret=True)
+    with pytest.raises(ValueError, match="max"):
+        if entry == "full":
+            bk.block_spmv_pallas(*args, **kw)
+        else:
+            bk.block_spmv_active_pallas(jnp.arange(4, dtype=jnp.int32),
+                                        *args, **kw)
+
+
 def test_frontier_expand_matches_engine_semantics():
     """OR kernel on the pull layout == out_neighbor_or on the snapshot."""
     from repro.core.graph import HostGraph, out_neighbor_or

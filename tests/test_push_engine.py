@@ -372,3 +372,24 @@ def test_push_pull_same_fixed_point(family, seed):
         sess.close()
     gap = float(np.abs(finals["push"] - finals["pull"]).max())
     assert gap < 2 * _bound(hg.n), (family, seed, gap)
+
+
+def test_push_on_the_kernel_matches_the_xla_tile_path():
+    """The push driver's pull-layout SpMV runs the Pallas live-tile walk
+    (here in the TPU interpreter): its streamed ranks match the same
+    session on the XLA tile path."""
+    hg = grid_road(16, seed=7)
+    batches, _ = _stream(hg, 2, rate=8 / hg.m, seed=60)
+    r0 = jnp.asarray(pr.numpy_reference(hg.snapshot(block_size=64),
+                                        iterations=200), jnp.float32)
+    ranks = {}
+    for backend in ("pallas", "xla"):
+        sess = PageRankSession.from_graph(hg, config=_cfg(
+            tau=1e-6 / hg.n, dtype="float32", backend=backend), r0=r0)
+        assert sess.backend == backend
+        for dels, ins in batches:
+            assert sess.update(dels, ins).converged
+        ranks[backend] = np.asarray(sess.ranks)
+        sess.close()
+    np.testing.assert_allclose(ranks["pallas"], ranks["xla"], rtol=0,
+                               atol=1e-6 * float(ranks["xla"].max()))

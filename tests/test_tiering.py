@@ -23,7 +23,9 @@ import jax.numpy as jnp
 
 from repro.api import EngineConfig, PageRankSession, SweepCapWarning
 from repro.core import blocked as blk
+from repro.core import pagerank as pr
 from repro.core import tiering
+from repro.core.delta import random_batch
 from repro.core.graph import HostGraph
 from repro.graphs.generators import grid_road, rmat
 
@@ -340,3 +342,26 @@ def test_edge_pager_budget_floor_raises():
     g = rmat(7, 4, seed=1).snapshot(block_size=64)
     with pytest.raises(ValueError, match="raise the budget"):
         tiering.EdgePager(g, budget_bytes=16)
+
+
+def test_tiered_on_the_kernel_matches_the_xla_tile_path():
+    """Under a budget, non-resident slots point at the slab's zero tile and
+    count as live, so the Pallas walk (here in the TPU interpreter) copies
+    them: the streamed ranks match the same session on the XLA tile path."""
+    hg = grid_road(16, seed=7)
+    dels, ins = random_batch(hg, 8 / hg.m, seed=60)
+    r0 = jnp.asarray(pr.numpy_reference(hg.snapshot(block_size=64),
+                                        iterations=200), jnp.float32)
+    ranks = {}
+    for backend in ("pallas", "xla"):
+        cfg = EngineConfig(engine="pallas", tau=1e-4 / hg.n, block_size=64,
+                           dtype="float32", backend=backend,
+                           device_budget_bytes=_pool_bytes(hg) * 3 // 4)
+        sess = PageRankSession.from_graph(hg, config=cfg, r0=r0)
+        assert sess.backend == backend
+        assert sess.update(dels, ins).converged
+        assert sess.report().tiering["misses"] > 0
+        ranks[backend] = np.asarray(sess.ranks)
+        sess.close()
+    np.testing.assert_allclose(ranks["pallas"], ranks["xla"], rtol=0,
+                               atol=1e-6 * float(ranks["xla"].max()))
