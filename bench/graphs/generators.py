@@ -7,13 +7,23 @@ edges undirected, as the specification asks): the yardstick must not move
 when the program's generators change.  Each returns ``(n, edges)``:
 an ``[m, 2] int64`` array of distinct ``(src, dst)`` pairs with no
 self-loops, sorted by ``src * n + dst``.
+
+A further generator needs no edit here: a configuration whose ``graph`` is
+not in :data:`GENERATORS` names a file ``graphs/<graph>.py`` whose function
+``<graph>`` takes its parameters by name and ``seed`` by keyword, and
+returns ``(n, edges)`` as these do.
 """
 from __future__ import annotations
 
+import importlib.util
 import inspect
+import os
+import re
 from typing import Tuple
 
 import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def edge_keys(n: int, src: np.ndarray, dst: np.ndarray) -> np.ndarray:
@@ -76,10 +86,27 @@ def graph500(scale: int, edge_factor: int = 16, *, a: float = 0.57,
 GENERATORS = {"grid_road": grid_road, "graph500": graph500}
 
 
-def make_graph(cfg: dict, seed) -> Tuple[int, np.ndarray]:
+def find_generator(graph: str, graphs_dir: str = HERE):
+    """The generator a configuration names: one of :data:`GENERATORS`, or
+    else the function ``<graph>`` of ``<graphs_dir>/<graph>.py``."""
+    if graph in GENERATORS:
+        return GENERATORS[graph]
+    path = os.path.join(graphs_dir, graph + ".py")
+    if not os.path.exists(path):
+        raise KeyError(f"no graph generator {graph!r}: neither one of "
+                       f"{sorted(GENERATORS)} nor {path}")
+    spec = importlib.util.spec_from_file_location(
+        "bench_graph_" + re.sub(r"\W", "_", graph), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return getattr(mod, graph)
+
+
+def make_graph(cfg: dict, seed, graphs_dir: str = HERE
+               ) -> Tuple[int, np.ndarray]:
     """Build the graph a configuration names: ``cfg["graph"]`` is the
-    generator, and its keyword parameters are the keys of ``cfg`` that
-    carry their names."""
-    gen = GENERATORS[cfg["graph"]]
+    generator (:func:`find_generator`), and its keyword parameters are the
+    keys of ``cfg`` that carry their names."""
+    gen = find_generator(cfg["graph"], graphs_dir)
     names = [p for p in inspect.signature(gen).parameters if p != "seed"]
     return gen(**{k: cfg[k] for k in names if k in cfg}, seed=seed)

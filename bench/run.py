@@ -8,7 +8,12 @@ The cell is found by name in ``BENCHMARK.json``: its configuration file
 traffic file (``traffic/<traffic>.json``: the load loop and its
 parameters), its limits (``limits/<workload>.json``) and one reader per
 metric (``metrics/<metric>.py``).  Adding a cell, a mix or a metric takes
-new files and entries, and no edit here.
+new files and entries, and no edit here.  So does a new deployment: every
+key of a configuration file that names an ``EngineConfig`` field reaches
+the session (``topology``, ``n_shards``, ``partitioner``, ``exchange``,
+``device_budget_bytes``, ``driver``, ...), and a graph generator that
+``graphs/generators.py`` does not hold is found in ``graphs/<graph>.py``.
+A four-chip cell runs a sharded configuration over its four chips.
 
 Every input is made from ``--seed``.  Set-up builds the graph, opens the
 session (its initial ranks come from its own solve on the device), warms
@@ -18,14 +23,16 @@ Afterwards the answers are checked against the plain reference
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the
 end-to-end metrics, or with ``--trace 1`` the per-layer ones), ``device``,
 with ``--trace 1`` a ``breakdown``, and last ``checks``, each number
-compared beside its limit.  Off a TPU, or with fewer chips than the cell
-asks for, it exits non-zero and prints no result.
+compared beside its limit.  Off a TPU, with fewer chips than the cell
+asks for, or with a configuration whose topology does not match the cell's
+chips, it exits non-zero and prints no result.
 """
 import time
 
 T_START = time.perf_counter()
 
 import argparse                                                # noqa: E402
+import dataclasses                                             # noqa: E402
 import gc                                                      # noqa: E402
 import importlib.util                                          # noqa: E402
 import json                                                    # noqa: E402
@@ -91,6 +98,7 @@ def load_spec(workload: str, root: str = ROOT) -> dict:
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
         "metrics_dir": os.path.join(here, "metrics"),
+        "graphs_dir": os.path.join(here, "graphs"),
     }
 
 
@@ -143,12 +151,35 @@ def peak_bytes(devs) -> int:
 # ---------------------------------------------------------------------------
 
 def engine_config(cfg: dict, n: int, backend=None):
+    """The session's ``EngineConfig``: every key of the configuration file
+    that names one of its fields, with ``tau = tau_rel / n``.  A
+    ``single`` topology runs the ``pallas`` engine, and ``backend``, where
+    given, takes the place of the file's tile backend; a ``sharded`` one
+    leaves the engine to resolve to ``distributed``."""
     from repro.api import EngineConfig
-    return EngineConfig(
-        engine="pallas", backend=backend or cfg["backend"],
-        driver=cfg["driver"], mode=cfg["mode"], dtype=cfg["dtype"],
-        alpha=cfg["alpha"], tau=cfg["tau_rel"] / n,
-        block_size=cfg["block_size"])
+    kw = {f.name: cfg[f.name] for f in dataclasses.fields(EngineConfig)
+          if f.name in cfg}
+    kw["tau"] = cfg["tau_rel"] / n
+    if kw.get("topology", "single") == "single":
+        kw["engine"] = "pallas"
+        if backend:
+            kw["backend"] = backend
+    return EngineConfig(**kw)
+
+
+def topology_error(spec: dict):
+    """Why the cell's chips and its configuration's topology disagree, or
+    None: a four-chip cell runs a sharded configuration, and a sharded
+    configuration states ``n_shards`` equal to the cell's chips."""
+    chips, cfg = spec["cell"]["chips"], spec["config"]
+    if cfg.get("topology", "single") == "single":
+        return None if chips == 1 else (
+            f"the cell asks for {chips} chips and its configuration's "
+            "topology is 'single'")
+    if cfg.get("n_shards") != chips:
+        return (f"the configuration is sharded with n_shards="
+                f"{cfg.get('n_shards')!r}, the cell asks for {chips} chips")
+    return None
 
 
 def _sizes(part: dict, k: int):
@@ -161,6 +192,24 @@ def _read_ok(values, vertices, n: int, k: int) -> bool:
             and (vertices is None
                  or bool(((np.asarray(vertices) >= 0)
                           & (np.asarray(vertices) < n)).all())))
+
+
+def path_check(sess, ecfg, chips: int, *, on_chip: bool) -> None:
+    """The session runs the engine its configuration resolves, on as many
+    devices as the cell asks for, and on the chip the ``pallas`` engine
+    runs compiled Pallas kernels."""
+    if sess.engine_name != ecfg.resolved_engine:
+        raise RuntimeError(f"the session took engine={sess.engine_name}, "
+                           f"its configuration {ecfg.resolved_engine}")
+    if on_chip and sess.engine_name == "pallas" and (
+            sess.backend != "pallas" or sess.interpret):
+        raise RuntimeError(f"the session took backend={sess.backend} "
+                           f"interpret={sess.interpret}, not compiled "
+                           "Pallas kernels")
+    if len(sess.device_footprint) != chips:
+        raise RuntimeError(f"the session holds devices "
+                           f"{sess.device_footprint}, the cell asks for "
+                           f"{chips} chips")
 
 
 def make_inputs(spec: dict, seed: int, seconds: float) -> dict:
@@ -178,7 +227,7 @@ def make_inputs(spec: dict, seed: int, seconds: float) -> dict:
     # the seed
     graph_seed = cfg.get("graph_seed")
     n, edges = make_graph(cfg, [seed, 0] if graph_seed is None
-                          else graph_seed)
+                          else graph_seed, spec["graphs_dir"])
     keys0 = edge_keys(n, edges[:, 0], edges[:, 1])
     symmetric = bool(cfg.get("symmetric", False))
     if traffic.get("slot_width"):
@@ -232,10 +281,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
         f"{time.perf_counter() - t_start:.3f} s")
     sess.warmup()
     log(f"[set-up] warmup {time.perf_counter() - t_start:.3f} s")
-    if devs is not None and (sess.backend != "pallas" or sess.interpret):
-        raise RuntimeError(f"the session took backend={sess.backend} "
-                           f"interpret={sess.interpret}, not compiled "
-                           "Pallas kernels")
+    path_check(sess, ecfg, spec["cell"]["chips"], on_chip=devs is not None)
     for dels, ins in warm_batches:       # one update per delta bucket
         res = sess.update(dels, ins)
         log(f"[set-up] warm batch of {len(dels) + len(ins)} edges: "
@@ -368,6 +414,10 @@ def main(argv=None) -> int:
     if args.seed < 0:
         ap.error("--seed must be >= 0")
     spec = load_spec(args.workload)
+    err = topology_error(spec)
+    if err is not None:
+        log(err)
+        return 2
     devs = find_chips(spec["cell"]["chips"])
     if devs is None:
         return 3

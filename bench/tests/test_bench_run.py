@@ -8,6 +8,7 @@ import os
 import shutil
 import subprocess
 import sys
+import textwrap
 import time
 
 import numpy as np
@@ -16,17 +17,19 @@ import pytest
 from bench import check, control, run
 from repro.api import PageRankSession
 
-CELLS = ["rmat-s15.stream", "road-1m.serve"]
+CELLS = ["rmat-s15.stream", "road-1m.serve", "road-1m.stream"]
 
 
 def tiny_spec(workload, **sizes):
     spec = run.load_spec(workload)
     cfg = spec["config"]
+    closed = spec["traffic"]["loop"] == "closed"
     if "scale" in cfg:
         cfg["scale"] = sizes.get("scale", 9)
     else:
-        cfg["side"] = sizes.get("side", 24)
-    if spec["traffic"]["loop"] == "closed":
+        # a closed loop's 120 batches delete more edges than side 24 has
+        cfg["side"] = sizes.get("side", 48 if closed else 24)
+    if closed:
         spec["traffic"]["max_batches"] = 120
     return spec
 
@@ -125,3 +128,107 @@ def test_run_without_the_program_exits_without_result(tmp_path):
          "1", "--seconds", "1", "--trace", "0"], cwd=tmp_path, env=env,
         capture_output=True, text=True, timeout=120)
     assert p.returncode != 0 and not _result_lines(p.stdout)
+
+
+def test_tiered_cell_is_correct(monkeypatch):
+    """A configuration's ``device_budget_bytes`` reaches the session: the
+    road cell with its tile pool tiered under a budget of about half the
+    pool (2 MiB of 64 x 64 f32 tiles at side 48) comes out correct."""
+    spec = tiny_spec("road-1m.stream")
+    spec["config"]["device_budget_bytes"] = 2 * 1024 * 1024
+    tiering = []
+    orig = run.path_check
+
+    def record(sess, ecfg, chips, **kw):
+        tiering.append(sess.report().tiering)
+        return orig(sess, ecfg, chips, **kw)
+
+    monkeypatch.setattr(run, "path_check", record)
+    out = run.run_cell(spec, 2**31 + 7, 0.5, False, backend="xla")
+    assert tiering[0]["budget_bytes"] == 2 * 1024 * 1024
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+
+
+_SHARDED = textwrap.dedent("""
+    import json, sys
+    sys.path[:0] = [{root!r}, {src!r}]
+    import jax
+    assert len(jax.devices()) == 4
+    from bench import run
+    spec = run.load_spec("road-1m.stream")
+    spec["cell"] = dict(spec["cell"], chips=4)
+    cfg = spec["config"]
+    cfg.update(side=48, topology="sharded", n_shards=4,
+               partitioner="contiguous", exchange="full")
+    spec["traffic"]["max_batches"] = 120
+    assert run.topology_error(spec) is None
+    footprints = []
+    orig = run.path_check
+    def record(sess, ecfg, chips, **kw):
+        footprints.append((sess.engine_name, len(sess.device_footprint)))
+        return orig(sess, ecfg, chips, **kw)
+    run.path_check = record
+    out = run.run_cell(spec, 2**31 + 9, 0.5, False)
+    print(json.dumps({{"footprints": footprints, "correct": out["correct"],
+                      "attempted": out["attempted"],
+                      "failed": out["failed"], "checks": out["checks"]}}))
+""")
+
+
+@pytest.mark.multidevice
+def test_sharded_cell_is_correct_on_four_devices():
+    """A sharded configuration over four (forced host) devices runs the
+    ``distributed`` engine through the whole run and comes out correct."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    script = _SHARDED.format(root=run.ROOT,
+                             src=os.path.join(run.ROOT, "src"))
+    p = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(_result_lines(p.stdout)[-1])
+    assert out["footprints"] == [["distributed", 4]]
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+
+
+def _four_chip_tree(tmp_path, cfg_update):
+    """A copy of the benchmark with one further cell on four chips, whose
+    configuration is road-1m's with ``cfg_update``."""
+    shutil.copy(os.path.join(run.ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(run.ROOT, "bench"), tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    b = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    b["configs"].append({"name": "road-x", "source": "test",
+                         "file": "bench/configs/road-x.json",
+                         "reduced": ["side"], "why": "test"})
+    b["workloads"].append({"name": "road-x.stream", "config": "road-x",
+                           "traffic": "stream-w16", "chips": 4,
+                           "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    cfg = json.loads((tmp_path / "bench/configs/road-1m.json").read_text())
+    cfg.update(cfg_update)
+    (tmp_path / "bench/configs/road-x.json").write_text(json.dumps(cfg))
+    shutil.copy(tmp_path / "bench/limits/road-1m.stream.json",
+                tmp_path / "bench/limits/road-x.stream.json")
+
+
+@pytest.mark.parametrize("cfg_update, why", [
+    ({}, "topology is 'single'"),
+    ({"topology": "sharded"}, "n_shards=None"),
+    ({"topology": "sharded", "n_shards": 2}, "n_shards=2"),
+], ids=["four_chips_single", "sharded_no_n_shards", "sharded_n_shards_2"])
+def test_topology_and_chips_that_disagree_exit_without_result(
+        tmp_path, cfg_update, why):
+    """Before set-up, a four-chip cell of a single-topology configuration,
+    or a sharded configuration whose ``n_shards`` is not the cell's chips,
+    exits non-zero with no result line."""
+    _four_chip_tree(tmp_path, cfg_update)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "road-x.stream",
+         "--seed", "1", "--seconds", "1", "--trace", "0"], cwd=tmp_path,
+        env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0 and not _result_lines(p.stdout)
+    assert why in p.stderr and "no TPU" not in p.stderr

@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 from bench import run
+from repro.api import EngineConfig, PageRankSession
+from repro.core.graph import HostGraph
 
 ROOT = run.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -143,3 +145,72 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
         inp["batches"][0][0], run.make_inputs(spec, 3, 2.0)["batches"][0][0])
     # the cells already there still load from the same tree
     assert run.load_spec("road-1m.serve", root=str(tmp_path))["per_layer"]
+
+    # a graph generator found by its own file, and the engine keys of a
+    # configuration reaching the session, with no edit either
+    (tmp_path / "bench/graphs/ring_lattice.py").write_text(
+        "import numpy as np\n"
+        "def ring_lattice(ring, *, seed):\n"
+        "    v = np.arange(ring, dtype=np.int64)\n"
+        "    s = np.concatenate([v, (v + 1) % ring])\n"
+        "    d = np.concatenate([(v + 1) % ring, v])\n"
+        "    k = np.unique(s * ring + d)\n"
+        "    return ring, np.stack([k // ring, k % ring], 1)\n")
+    ring = {"graph": "ring_lattice", "ring": 200, "symmetric": True,
+            "alpha": 0.85, "tau_rel": 1e-4, "dtype": "float32",
+            "block_size": 64, "reduced": []}
+    variants = {
+        "ring-sharded": dict(ring, topology="sharded", n_shards=1,
+                             partitioner="hash", exchange="bf16"),
+        "ring-tiered": dict(ring, backend="xla",
+                            device_budget_bytes=1 << 20),
+    }
+    for name, cfg in variants.items():
+        b["configs"].append({"name": name, "source": "test",
+                             "file": f"bench/configs/{name}.json",
+                             "reduced": [], "why": "test"})
+        b["workloads"].append({"name": name + ".trickle", "config": name,
+                               "traffic": "trickle", "chips": 1,
+                               "why": "test"})
+        (tmp_path / f"bench/configs/{name}.json").write_text(
+            json.dumps(cfg))
+        (tmp_path / f"bench/limits/{name}.trickle.json").write_text(
+            json.dumps({"rank_err_tau": 1.0, "graph_diff": 0,
+                        "read_err_tau": 1.0}))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+    (tmp_path / "bench/traffic/trickle.json").write_text(json.dumps(
+        {"loop": "closed", "update": {"deletions": 2, "insertions": 2},
+         "max_batches": 4}))
+    for name, cfg in variants.items():
+        spec = run.load_spec(name + ".trickle", root=str(tmp_path))
+        assert run.topology_error(spec) is None
+        inp = run.make_inputs(spec, 3, 2.0)
+        assert inp["n"] == 200 and len(inp["keys0"]) == 400
+        ecfg = run.engine_config(spec["config"], inp["n"])
+        sess = PageRankSession.from_graph(
+            HostGraph(inp["n"], inp["edges"]), config=ecfg)
+        try:
+            for key in set(cfg) & set(EngineConfig.valid_keys()):
+                assert getattr(sess.config, key) == cfg[key], key
+            assert sess.engine_name == ecfg.resolved_engine == (
+                "distributed" if "topology" in cfg else "pallas")
+        finally:
+            sess.close()
+
+
+@pytest.mark.parametrize("config", ["rmat-s15", "road-1m"])
+@pytest.mark.parametrize("backend", [None, "xla"])
+def test_accepted_configuration_builds_the_same_engine_config(config,
+                                                              backend):
+    """Each accepted configuration's ``EngineConfig`` equals, field by
+    field, the one the harness built from its eight keys before a
+    configuration could carry the other engine axes."""
+    with open(os.path.join(ROOT, "bench", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    n = 1 << 15
+    before = EngineConfig(
+        engine="pallas", backend=backend or cfg["backend"],
+        driver=cfg["driver"], mode=cfg["mode"], dtype=cfg["dtype"],
+        alpha=cfg["alpha"], tau=cfg["tau_rel"] / n,
+        block_size=cfg["block_size"])
+    assert run.engine_config(cfg, n, backend) == before
