@@ -997,19 +997,22 @@ class PageRankSession:
             ins_a = np.asarray(ins_eff, np.int64).reshape(-1, 2)
             sources = np.unique(np.concatenate([dels_a[:, 0],
                                                 ins_a[:, 0]]))
-            if len(sources):
-                p_src = np.asarray(self.R[jnp.asarray(sources)])
-                sidx, svals = pshe.residual_seed_host(
-                    self._hg_prev, self.hg, sources, p_src,
-                    deg_old_host[sources], self._out_deg_host[sources],
-                    float(cfg.alpha))
-            else:
-                sidx = np.zeros(0, np.int64)
-                svals = np.zeros(0, self._dtype)
-            # the scatter runs even for an empty batch so warmup() traces
-            # it at the base bucket, like the operand scatter
-            self._residual = pshe.scatter_residual(self._residual, sidx,
-                                                   svals)
+            # the host work push adds to a batch: the read of p at the
+            # sources (a host sync), the enumeration and the scatter
+            with TraceAnnotation("session.push_seed"):
+                if len(sources):
+                    p_src = pshe.gather_host(self.R, sources)
+                    sidx, svals = pshe.residual_seed_host(
+                        self._hg_prev, self.hg, sources, p_src,
+                        deg_old_host[sources], self._out_deg_host[sources],
+                        float(cfg.alpha))
+                else:
+                    sidx = np.zeros(0, np.int64)
+                    svals = np.zeros(0, self._dtype)
+                # the scatter runs even for an empty batch so warmup()
+                # traces it at the base bucket, like the operand scatter
+                self._residual = pshe.scatter_residual(self._residual, sidx,
+                                                       svals)
             return self.R, sidx
         if variant == "nd":
             self._residual = self._residual_recompute(self.R)

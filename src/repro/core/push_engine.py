@@ -33,13 +33,21 @@ Everything rides the existing streaming machinery:
   ``lax.switch`` (K ≥ |pushable|, so selection is complete — the bucket
   bounds the top-k cost and keeps every launch shape static and
   retrace-free);
-* one ``lax.while_loop`` with zero host syncs; convergence is the
-  per-vertex residual bound (``max|r| ≤ tau`` — pushing v moves p[v] by
-  exactly r[v], so this is the same strength as the pull driver's
-  ``maxdr ≤ tau`` stop) plus the PR-9 ulp-floor escape
-  (``max|r| ≤ 16·eps·max|p|`` — the regime where pushes are no longer
-  representable in ``p``); ``‖r‖₁`` is still reported, giving the
-  computable a-posteriori L∞ bound ``‖r‖₁·α/(1−α)``;
+* one ``lax.while_loop`` with zero host syncs; convergence is a
+  per-vertex residual bound scaled by out-degree,
+  ``|r[v]| ≤ tau·deg(v)/deg_max`` — so ``max|r| ≤ tau`` holds at exit,
+  as under the pull driver's ``maxdr ≤ tau`` stop, and the error left,
+  ``(I − M)⁻¹·r``, is at most ``tau/(1−α)`` at every vertex of an
+  undirected graph.  A uniform ``|r[v]| ≤ tau`` would let the sub-tau
+  residuals of many low-degree vertices add up at the hubs of a
+  power-law graph (hundreds of tau at ``rmat-s15``'s degree-5,969 hub);
+  the degree scaling gives the residual the shape the pull driver's
+  sweeps leave.  Plus the ulp-floor escape, per vertex
+  (``|r[v]| ≤ 16·eps·|p[v]|`` — pushes no longer representable in
+  ``p[v]``; a hub's rank rounds far coarser than a leaf's, so one floor
+  at ``max|p|`` would stop the leaves early); ``‖r‖₁`` is still
+  reported, giving the computable a-posteriori L∞ bound
+  ``‖r‖₁·α/(1−α)``;
 * tiering composes without mid-sweep syncs: a push delivers to the
   device-*resident* candidate destination rows only; a pushed-to
   non-resident row goes **stale** and is recorded in the PR-9 deferred
@@ -71,8 +79,8 @@ from repro.core.graph import HostGraph
 from repro.kernels.block_spmv import ops
 
 # stats vector layout returned by _push_driver
-STATS_LEN = 8   # sweeps, pushed_blocks, cand_blocks, edges, l1, maxr,
-#                 converged, stalled
+STATS_LEN = 7   # sweeps, pushed_blocks, cand_blocks, edges, l1, maxr,
+#                 converged
 
 
 @partial(jax.jit, static_argnames=("n", "block_size", "max_iterations",
@@ -111,36 +119,34 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
     alpha_c = alpha.astype(dtype)
     tau_c = tau.astype(dtype)
     base_floor = (1.0 - alpha_c) / n
+    # per-vertex threshold: tau at the widest vertex, scaled by out-degree
+    # (module docstring) — never above tau, so max|r| ≤ tau at exit
+    tau_v = tau_c * deg / jnp.where(valid, deg, 0).max()
 
     P = jnp.where(valid, P0[:n_pad], 0).astype(dtype)
     Rr = jnp.where(valid, R0[:n_pad], 0).astype(dtype)
 
     def cond(state):
-        (_, _, it, converged, stalled, _, _) = state
-        return ~converged & ~stalled & (it < max_iterations)
+        (_, _, it, converged, _, _) = state
+        return ~converged & (it < max_iterations)
 
     def body(state):
-        P, Rr, it, converged, stalled, deferred, ctr = state
+        P, Rr, it, converged, deferred, ctr = state
         with jax.named_scope("df.expand"):
-            aRr = jnp.abs(Rr).reshape(n_rb, B)
-            rb_mass = aRr.sum(axis=1)
-            rb_maxr = aRr.max(axis=1)
-            maxr = rb_maxr.max()
-            # ulp-floor escape (PR-9 maxdr analogue): every remaining
-            # residual is below the rounding granularity of p — pushing
-            # cannot move p
-            at_floor = maxr <= 16.0 * eps * jnp.maximum(jnp.abs(P).max(),
-                                                        base_floor)
-            # per-vertex exit: pushing v moves p[v] by exactly r[v], so
-            # max|r| ≤ tau is the same strength as the pull driver's
-            # maxdr ≤ tau stop — no vertex's next move would exceed tau
-            conv_now = (maxr <= tau_c) | at_floor
-            pushable = rb_maxr > tau_c
+            aRr = jnp.abs(Rr)
+            # ulp-floor escape (the pull's maxdr analogue), per vertex: a
+            # residual below the rounding granularity of p[v] cannot move
+            # p[v]
+            lim = jnp.maximum(tau_v, 16.0 * eps * jnp.maximum(jnp.abs(P),
+                                                              base_floor))
+            over = valid & (aRr > lim)
+            rb_mass = aRr.reshape(n_rb, B).sum(axis=1)
+            pushable = over.reshape(n_rb, B).any(axis=1)
             n_push = pushable.sum(dtype=jnp.int32)
-            do = ~conv_now & (n_push > 0)
-            # defensive only: maxr > tau with every per-block max ≤ tau is
-            # impossible (maxr IS the max over the per-block maxima)
-            stall_now = ~conv_now & (n_push == 0)
+            # per-vertex exit: pushing v moves p[v] by exactly r[v], so no
+            # vertex's next move exceeds its threshold
+            conv_now = n_push == 0
+            do = ~conv_now
 
             # -- bucketed top-mass source selection: smallest ladder
             #    bucket K ≥ |pushable|, top-K blocks by residual mass via
@@ -182,11 +188,11 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
 
         # -- the push: scatter-semiring SpMV over candidate dst blocks.
         #    Per-vertex threshold (Andersen–Chung–Lang form): only entries
-        #    with |r| > tau move — sub-tau entries stay in r, which is
-        #    exactly what the max|r| ≤ tau exit permits — so edge work is
-        #    Σ out-deg over *pushed vertices*, not over whole blocks. --------
+        #    above their threshold move — the rest stay in r, which is
+        #    exactly what the exit permits — so edge work is Σ out-deg over
+        #    *pushed vertices*, not over whole blocks. ----------------------
         with jax.named_scope("df.sweep"):
-            sel_v = jnp.repeat(sel, B) & valid & (jnp.abs(Rr) > tau_c)
+            sel_v = jnp.repeat(sel, B) & over
             moved = jnp.where(sel_v, Rr, 0)
             pushed = ops.block_spmv_push_bucketed(
                 mat, moved * inv_deg, sel, cids, n_cand,
@@ -204,21 +210,18 @@ def _push_driver(mat: ops.BlockSparse, P0, R0, valid, out_deg, rb_out,
                     pushed_b + jnp.where(do, n_push, 0).astype(cdt),
                     cand_b + n_cand.astype(cdt),
                     edges + e_sweep)
-        return (P1, R1, it + 1, converged | conv_now,
-                stalled | stall_now, deferred, ctr1)
+        return (P1, R1, it + 1, converged | conv_now, deferred, ctr1)
 
     zero = jnp.zeros((), cdt)
-    init = (P, Rr, jnp.int32(0), jnp.asarray(False), jnp.asarray(False),
+    init = (P, Rr, jnp.int32(0), jnp.asarray(False),
             jnp.zeros((n_rb,), bool), (zero, zero, zero, zero))
-    P, Rr, _, converged, stalled, deferred, ctr = lax.while_loop(
-        cond, body, init)
+    P, Rr, _, converged, deferred, ctr = lax.while_loop(cond, body, init)
     sweeps, pushed_b, cand_b, edges = ctr
     fdt = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     stats = jnp.stack([
         sweeps.astype(fdt), pushed_b.astype(fdt), cand_b.astype(fdt),
         edges.astype(fdt), jnp.abs(Rr).sum().astype(fdt),
-        jnp.abs(Rr).max().astype(fdt), converged.astype(fdt),
-        stalled.astype(fdt)])
+        jnp.abs(Rr).max().astype(fdt), converged.astype(fdt)])
     return P, Rr, stats, deferred
 
 
@@ -231,8 +234,7 @@ def push_stats_from_vec(sv: np.ndarray) -> Tuple[SweepStats, dict]:
         sim_time_ms=0.0, converged=bool(sv[6] > 0), dnf=False)
     extras = {"pushed_blocks": int(sv[1]),
               "residual_l1": float(sv[4]),
-              "max_residual": float(sv[5]),
-              "stalled": bool(sv[7] > 0)}
+              "max_residual": float(sv[5])}
     return stats, extras
 
 
@@ -308,6 +310,23 @@ def scatter_residual(Rr, idx: np.ndarray, vals: np.ndarray):
     pi[:len(idx)] = idx
     pv[:len(vals)] = vals
     return _scatter_residual(Rr, jnp.asarray(pi), jnp.asarray(pv))
+
+
+@jax.jit
+def _gather(x, idx):
+    return x[idx]
+
+
+def gather_host(x, idx: np.ndarray) -> np.ndarray:
+    """``x[idx]`` read back to the host through one bucketed device
+    gather: the index list is padded to the delta-batch bucket, so a
+    stream of batches with varying source counts reuses one compiled
+    gather rather than compiling one per count."""
+    idx = np.asarray(idx, np.int64).reshape(-1)
+    k = ops.capacity_bucket(max(len(idx), 1), ops.DELTA_BATCH_BUCKET)
+    pi = np.zeros(k, np.int64)
+    pi[:len(idx)] = idx
+    return np.asarray(_gather(x, jnp.asarray(pi)))[:len(idx)]
 
 
 @partial(jax.jit, static_argnames=("n", "interpret", "backend"))

@@ -6,11 +6,11 @@ what interpret mode cannot — scalar-memory (SMEM) overflow of the
 prefetched slot tables, index maps that trace to int64 under x64, block
 shapes the chip refuses — at the n = 1,048,576 layout of
 ``grid_road(1024)``: 16,384 row-blocks of B = 64, 16 tile slots per row,
-float32 ranks, and the kernel also at the ``rmat-s15`` layout of 512
-row-blocks of 512 slots.  The suite enables x64, so these compiles also
-guard the kernels against it.  One more case lowers a kernel from two
-checkouts and checks that the scripts' cache settings give both the same
-cache key.
+float32 ranks, and the kernel and the push driver also at the
+``rmat-s15`` layout of 512 row-blocks of 512 slots.  The suite enables
+x64, so these compiles also guard the kernels against it.  One more case
+lowers a kernel from two checkouts and checks that the scripts' cache
+settings give both the same cache key.
 
 The topology is described inside a module-scoped fixture (never at
 import): only one process may load the TPU library at a time, so only the
@@ -153,6 +153,28 @@ def test_push_driver_compiles_at_1m(one_chip):
         S((N_RB, N_RB), b), S((N_RB,), b), S((), f32), S((), f32),
         n=N_PAD, block_size=B, max_iterations=MAX_ITERATIONS,
         interpret=False, backend="pallas").compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_push_driver_compiles_at_rmat_s15(one_chip):
+    """The push driver at the ``rmat-s15`` layout (512 row-blocks of 512
+    slots, a 262,144-tile pool), where its top ladder bucket's slot tables
+    split into several launches, as the kernel's do."""
+    S = _spec(one_chip)
+    f32, b = jnp.float32, jnp.bool_
+    n_rb, mt, cap = 512, 512, 262_144
+    n_pad = n_rb * B
+    mat = BlockSparse(n_rows=n_pad, n_cols=n_pad, block=B, max_tiles=mt,
+                      tiles=S((cap,) + tile_shape(B), f32),
+                      tile_cols=S((n_rb, mt), jnp.int32),
+                      tile_idx=S((n_rb * mt,), jnp.int32))
+    compiled = pshe._push_driver.lower(
+        mat, S((n_pad,), f32), S((n_pad,), f32), S((n_pad,), b),
+        S((n_pad,), jnp.int32), S((n_rb,), jnp.int32),
+        S((n_rb, n_rb), b), S((n_rb,), b), S((), f32), S((), f32),
+        n=n_pad, block_size=B, max_iterations=MAX_ITERATIONS,
+        interpret=False, backend="pallas").compile()
+    assert bk.launch_rows(mt) < n_rb
     assert "tpu_custom_call" in compiled.as_text()
 
 
