@@ -506,11 +506,12 @@ class PageRankSession:
         self.valid = g0.vertex_valid
         # device-resident engine operands, patched in place per batch by
         # _apply_operand_delta (the host-side numpy twins live in inc.aux
-        # for non-stream callers)
+        # for non-stream callers; bmat is uploaded as a copy because its
+        # twin is patched in place)
         self._out_deg = jnp.asarray(g0.out_deg)
         self._rb_in = jnp.asarray(self.inc.aux.rb_in)
         self._rb_out = jnp.asarray(self.inc.aux.rb_out)
-        self._bmat = jnp.asarray(self.inc.aux.bmat)
+        self._bmat = jnp.array(self.inc.aux.bmat, copy=True)
         # host-truth twin of the out-degree mirror (rb_in/rb_out/bmat have
         # theirs in inc.aux), maintained in O(batch) — what the integrity
         # scrubber digests the device mirror against
@@ -1461,7 +1462,7 @@ class PageRankSession:
             self._out_deg_host = np.asarray(g.out_deg).copy()
             self._rb_in = jnp.asarray(self.inc.aux.rb_in)
             self._rb_out = jnp.asarray(self.inc.aux.rb_out)
-            self._bmat = jnp.asarray(self.inc.aux.bmat)
+            self._bmat = jnp.array(self.inc.aux.bmat, copy=True)
             self._scatter_fault = None
             # a rebuilt pool restarts the capacity ladder at its own
             # bucket; compiles it causes are recovery cost, not retraces
@@ -2574,6 +2575,9 @@ class PageRankSession:
             new._out_deg_host = self._out_deg_host.copy()
         if self.inc is not None:
             aux = self.inc.aux
+            # the copies are required: MatrixAux.apply_delta patches bmat
+            # in place, so a shared array would carry one branch's tile
+            # presence into the other
             new.inc = IncrementalPullMatrix(
                 self.inc.mat,
                 MatrixAux(bmat=aux.bmat.copy(), rb_in=aux.rb_in.copy(),

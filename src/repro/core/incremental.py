@@ -75,6 +75,15 @@ class MatrixAux:
     * ``rb_out`` — out-edge count per src-block (expansion work metric).
 
     All three update in O(batch) from the signed delta coordinates.
+    ``bmat`` is patched *in place* (it is [n_rb, n_cb]: 268 MB at a
+    16,384-block grid, against the batch's handful of cells); every
+    device array built from it is an explicit copy
+    (``jnp.array(..., copy=True)``), so no device mirror shares its
+    buffer, and :meth:`PageRankSession.fork` copies it for the branch.
+    A fork taken concurrently with a patch (the service's read-snapshot
+    refresh runs outside the slot lock) copies the old map plus some of
+    the batch's new cells: presence only ever turns cells on, and read
+    snapshots serve ranks only, never consulting ``bmat``.
     """
     bmat: np.ndarray     # [n_rb, n_cb] bool
     rb_in: np.ndarray    # [n_rb] i32
@@ -90,13 +99,9 @@ class MatrixAux:
     def apply_delta(self, block: int, rows: np.ndarray, cols: np.ndarray,
                     vals: np.ndarray) -> None:
         """O(batch) update from signed pull-layout coordinates (rows = dst,
-        cols = src, vals = ±1): block degrees move by the signed counts;
-        tile presence ORs in every touched pair.
-
-        Fields are *rebound* to fresh arrays, never mutated in place: on
-        CPU, ``jnp.asarray`` may alias a numpy buffer zero-copy (the stream
-        runner's device mirrors, ``run_pallas(aux=...)`` operands), and an
-        in-place write here would race the transfer and corrupt them."""
+        cols = src, vals = ±1): block degrees move by the signed counts
+        (``rb_in``/``rb_out`` are rebound, [n_rb] each); tile presence ORs
+        in every touched pair, in place."""
         if len(rows) == 0:
             return
         rb = np.asarray(rows, np.int64) // block
@@ -107,9 +112,7 @@ class MatrixAux:
             rb, weights=v, minlength=n_rb).astype(self.rb_in.dtype)
         self.rb_out = self.rb_out + np.bincount(
             cb, weights=v, minlength=n_rb).astype(self.rb_out.dtype)
-        bmat = self.bmat.copy()
-        bmat[rb, cb] = True
-        self.bmat = bmat
+        self.bmat[rb, cb] = True
 
 
 class IncrementalPullMatrix:

@@ -322,7 +322,8 @@ def run_pallas(g: GraphSnapshot, R0: jnp.ndarray, affected0: jnp.ndarray,
 
     if aux is not None:
         rb_in, rb_out = jnp.asarray(aux.rb_in), jnp.asarray(aux.rb_out)
-        bmat = jnp.asarray(aux.bmat)
+        # a copy: MatrixAux patches its bmat in place after this returns
+        bmat = jnp.array(aux.bmat, copy=True)
     else:
         rb_in, rb_out = g.block_in_edges(), g.block_out_edges()
         bmat = ops.block_adjacency(mat)

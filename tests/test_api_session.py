@@ -15,7 +15,7 @@ from repro.api import (EngineConfig, PageRankService, PageRankSession,
 from repro.core import pagerank as pr
 from repro.core.delta import random_batch
 from repro.core.frontier import batch_to_device
-from repro.graphs.generators import rmat
+from repro.graphs.generators import grid_road, rmat
 
 
 @pytest.fixture(scope="module")
@@ -355,6 +355,28 @@ class TestSessionLifecycle:
         assert sess.update(d2, i2).stats.converged
         assert twin.report().n_updates == 1
         assert sess.report().n_updates == 1
+
+
+    def test_fork_bmat_twins_are_independent(self):
+        """The host presence map is patched in place per batch, so each
+        branch of a fork owns its copy: a batch on one leaves the other's
+        map as it was, in both directions."""
+        sess = PageRankSession.from_graph(
+            grid_road(32, seed=0),
+            config=EngineConfig(engine="pallas", block_size=64))
+        twin = sess.fork()
+        base = sess.inc.aux.bmat.copy()
+        none = np.zeros((0, 2), np.int64)
+        # one edge (src, dst) into each of two cells (dst-block, src-block)
+        # that are still off
+        (r0, c0), (r1, c1) = np.argwhere(~base)[[0, -1]]
+        sess.update(none, np.array([[c0 * 64 + 5, r0 * 64 + 3]]))
+        assert sess.inc.aux.bmat[r0, c0]
+        np.testing.assert_array_equal(twin.inc.aux.bmat, base)
+        after = sess.inc.aux.bmat.copy()
+        twin.update(none, np.array([[c1 * 64 + 5, r1 * 64 + 3]]))
+        assert twin.inc.aux.bmat[r1, c1] and not twin.inc.aux.bmat[r0, c0]
+        np.testing.assert_array_equal(sess.inc.aux.bmat, after)
 
 
 # ---------------------------------------------------------------------------

@@ -96,6 +96,24 @@ def test_torn_scatter_detected_by_mirror_digests(graph, kind):
     sess.close()
 
 
+def test_bmat_mirror_owns_its_buffer(graph):
+    """The device presence mirror is uploaded as a copy of its host twin,
+    which is patched in place per batch: a write to the twin leaves the
+    mirror as it was, and the mirror digests see the two disagree — at
+    construction and again after the rebuild rung re-uploads it."""
+    sess = PageRankSession.from_graph(graph, config=_cfg())
+    for _ in range(2):
+        bmat = sess.inc.aux.bmat
+        mirror = np.asarray(sess._bmat).copy()
+        bmat[0, -1] = not bmat[0, -1]
+        np.testing.assert_array_equal(np.asarray(sess._bmat), mirror)
+        rep = sess.verify(repair=True, deep=False)
+        assert any(f["check"] == "mirror_digest" and f["mirror"] == "bmat"
+                   for f in rep.failures), rep.failures
+        assert rep.ok and "rebuild" in rep.repairs
+    sess.close()
+
+
 def test_graph_corruption_restores_from_store(graph, tmp_path):
     """Host-truth damage (the deep graph digest) cannot be repaired from
     the host — the ladder escalates to the checkpoint+WAL restore rung."""

@@ -143,6 +143,43 @@ def test_matrix_aux_tracks_fresh_recompute():
     assert res.converged
 
 
+def _aux_on_lattice():
+    """MatrixAux of a 16 x 16 block grid whose presence map is banded, so
+    a batch can reach cells that are still off."""
+    g = grid_road(32, seed=0).snapshot(block_size=64)
+    return IncrementalPullMatrix.from_snapshot(g).aux
+
+
+def test_matrix_aux_patches_bmat_in_place():
+    """The presence map is patched in its own buffer, and exactly the
+    batch's (dst-block, src-block) cells turn on — inserted and deleted
+    coordinates alike, since presence is monotone."""
+    aux = _aux_on_lattice()
+    buf = aux.bmat
+    before = buf.copy()
+    off = np.argwhere(~before)[[0, -1]]
+    on = np.argwhere(before)[0]
+    rb = np.array([off[0, 0], off[1, 0], on[0]])
+    cb = np.array([off[0, 1], off[1, 1], on[1]])
+    rows, cols = rb * 64 + 3, cb * 64 + 5
+    aux.apply_delta(64, rows, cols, np.array([1.0, 1.0, -1.0]))
+    assert aux.bmat is buf and np.shares_memory(aux.bmat, buf)
+    want = before.copy()
+    want[rb, cb] = True
+    np.testing.assert_array_equal(aux.bmat, want)
+    assert int(aux.bmat.sum()) == int(before.sum()) + 2
+
+
+def test_matrix_aux_empty_batch_leaves_bmat():
+    aux = _aux_on_lattice()
+    buf = aux.bmat
+    before = buf.copy()
+    none = np.zeros(0, np.int64)
+    aux.apply_delta(64, none, none, np.zeros(0))
+    assert aux.bmat is buf
+    np.testing.assert_array_equal(aux.bmat, before)
+
+
 # ---------------------------------------------------------------------------
 # streaming runtime
 # ---------------------------------------------------------------------------
